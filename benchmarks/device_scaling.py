@@ -187,6 +187,9 @@ def _worker(n_devices: int, quick: bool) -> None:
 
 def _spawn(n_devices: int, quick: bool, x64: bool) -> dict:
     env = dict(os.environ)
+    # the workers simulate a mesh with host devices: pin them to the CPU so
+    # they never reach for an accelerator the parent process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env.pop("JAX_ENABLE_X64", None)
     if x64:

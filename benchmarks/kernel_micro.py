@@ -27,7 +27,7 @@ def run():
 
     # fct_count: N x L tokens histogrammed over V
     n, tl, v = 8192, 16, 32768
-    toks = jnp.asarray(rng.integers(0, v, (n, tl)), jnp.int32)
+    toks = jnp.asarray(rng.integers(0, v, (tl, n)), jnp.int32)  # [L, N]
     w = jnp.asarray(rng.integers(0, 9, (n,)), jnp.int32)
     ref_fn = jax.jit(lambda t, ww: fct_ref.weighted_histogram(t, ww, v))
     us = timed(lambda: jax.block_until_ready(ref_fn(toks, w)))
@@ -36,7 +36,7 @@ def run():
     emit("fct_count/ref_segment_sum", us,
          f"tpu_kernel_est_us={max(mxu_s, hbm_s) * 1e6:.1f}")
 
-    small = jnp.asarray(rng.integers(0, 512, (256, 8)), jnp.int32)
+    small = jnp.asarray(rng.integers(0, 512, (8, 256)), jnp.int32)  # [L, N]
     sw = jnp.asarray(rng.integers(0, 9, (256,)), jnp.int32)
     us = timed(lambda: jax.block_until_ready(
         weighted_histogram(small, sw, 512, backend="interpret")), iters=1)

@@ -29,6 +29,8 @@ def main() -> None:
                     metavar="PATH", help="write results as JSON")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from benchmarks import (common, device_scaling, ingest_stream,
                             kernel_micro, multi_query, response_time,
                             serving_load, shares_comm, shuffle_size,

@@ -145,12 +145,12 @@ def _sds(shape, dtype=None):
 
 def batched_abstract_args(sig: PlanSignature, n_stack: int):
     """ShapeDtypeStruct pytree matching ``stack_group``'s [N, P, ...] output
-    (the host-stacked families' global arguments)."""
+    (the host-stacked families' global arguments; columns lane-major)."""
     p = sig.n_devices
 
     def rel(rsig: RelationSig, key_tail: Tuple[int, ...]):
-        return {"text": _sds((n_stack, p, rsig.rows, rsig.text_len)),
-                "keys": _sds((n_stack, p, rsig.rows) + key_tail),
+        return {"text": _sds((n_stack, p, rsig.text_len, rsig.rows)),
+                "keys": _sds((n_stack, p) + key_tail + (rsig.rows,)),
                 "send": _sds((n_stack, p, p, rsig.cap))}
 
     fact = rel(sig.fact, (sig.m,))
@@ -160,13 +160,13 @@ def batched_abstract_args(sig: PlanSignature, n_stack: int):
 
 def store_abstract_args(sig: PlanSignature, n_stack: int):
     """ShapeDtypeStruct pytree matching ``store_group_args``: per relation,
-    ``n_stack`` device-resident [P, S, ...] column arrays plus the stacked
+    ``n_stack`` device-resident lane-major [P, ..., S] column arrays plus the stacked
     host send tables; the fact adds its per-CN key-column indices."""
     p = sig.n_devices
 
     def rel(rsig: RelationSig, key_tail: Tuple[int, ...]):
-        return {"text": [_sds((p, rsig.rows, rsig.text_len))] * n_stack,
-                "keys": [_sds((p, rsig.rows) + key_tail)] * n_stack,
+        return {"text": [_sds((p, rsig.text_len, rsig.rows))] * n_stack,
+                "keys": [_sds((p,) + key_tail + (rsig.rows,))] * n_stack,
                 "send": _sds((n_stack, p, p, rsig.cap))}
 
     fact = rel(sig.fact, (sig.fact.key_width,))

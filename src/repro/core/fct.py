@@ -21,12 +21,11 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.accum import AccumPolicy
-from repro.core.plan import CNPlan
+from repro.core.plan import CNPlan, lane_major
 from repro.data.schema import StarSchema
 from repro.kernels.fct_count.ops import weighted_histogram
 
@@ -49,36 +48,38 @@ def _acc_dtype(accum: Optional[AccumPolicy] = None):
 def _route(text, keys, send):
     """Gather rows into per-destination buffers and all_to_all them.
 
-    text [S, L]; keys [S] or [S, m]; send [P, C] (local row idx, -1 pad).
-    Returns (text [P*C, L], keys [P*C(, m)], mask [P*C]) of received rows.
+    Columns are lane-major (rows on the LAST axis; see
+    ``repro.core.plan.lane_major``): text [L, S]; keys [S] or [m, S];
+    send [P, C] (local row idx, -1 pad).  Returns (text [L, P*C],
+    keys [P*C] / [m, P*C], mask [P*C]) of received rows.
     """
-    idx = jnp.maximum(send, 0)
+    p, c = send.shape
+    idx = jnp.maximum(send, 0).reshape(-1)
     mask = send >= 0
-    btext = jnp.take(text, idx.reshape(-1), axis=0)
-    btext = btext.reshape(send.shape + text.shape[1:])
-    bkeys = jnp.take(keys, idx.reshape(-1), axis=0)
-    bkeys = bkeys.reshape(send.shape + keys.shape[1:])
-    rtext = lax.all_to_all(btext, "w", split_axis=0, concat_axis=0, tiled=True)
-    rkeys = lax.all_to_all(bkeys, "w", split_axis=0, concat_axis=0, tiled=True)
+    btext = jnp.take(text, idx, axis=-1).reshape(text.shape[:-1] + (p, c))
+    bkeys = jnp.take(keys, idx, axis=-1).reshape(keys.shape[:-1] + (p, c))
+    rtext = lax.all_to_all(btext, "w", split_axis=btext.ndim - 2,
+                           concat_axis=btext.ndim - 2, tiled=True)
+    rkeys = lax.all_to_all(bkeys, "w", split_axis=bkeys.ndim - 2,
+                           concat_axis=bkeys.ndim - 2, tiled=True)
     rmask = lax.all_to_all(mask, "w", split_axis=0, concat_axis=0, tiled=True)
-    flat = rtext.shape[0] * rtext.shape[1]
-    return (rtext.reshape((flat,) + rtext.shape[2:]),
-            rkeys.reshape((flat,) + rkeys.shape[2:]),
-            rmask.reshape(flat))
+    return (rtext.reshape(text.shape[:-1] + (p * c,)),
+            rkeys.reshape(keys.shape[:-1] + (p * c,)),
+            rmask.reshape(p * c))
 
 
 def _route_cn(fact, dims):
     """MR¹ shuffle stage shared by the fused, two-job and store paths: route
     every relation of one CN per its static send table.
 
-    ``fact["keys"]`` is either the CN's selected key columns ``[S, m]`` (host
-    paths) or the FULL-width store-resident matrix ``[S, m_all]`` with
+    ``fact["keys"]`` is either the CN's selected key columns ``[m, S]`` (host
+    paths) or the FULL-width store-resident matrix ``[m_all, S]`` with
     ``fact["cols"]`` naming the CN's columns — the store uploads each fact
     tuple set once and every CN over it selects its columns on device.
     """
     fkeys = fact["keys"]
     if "cols" in fact:
-        fkeys = jnp.take(fkeys, fact["cols"], axis=1)
+        fkeys = jnp.take(fkeys, fact["cols"], axis=0)
     routed_fact = _route(fact["text"], fkeys, fact["send"])
     routed_dims = [_route(d["text"], d["keys"], d["send"]) for d in dims]
     return routed_fact, routed_dims
@@ -96,7 +97,7 @@ def _mr1_volumes(routed_fact, routed_dims, domains: Tuple[int, ...],
     for (dtext, dkeys, dmask), dom in zip(routed_dims, domains):
         nums.append(jnp.zeros((dom,), jnp.int32).at[dkeys].add(
             dmask.astype(jnp.int32), mode="drop"))
-    probes = [nums[i][fkeys[:, i]].astype(acc) for i in range(m)]
+    probes = [nums[i][fkeys[i]].astype(acc) for i in range(m)]
     fvalid = fmask.astype(acc)
     vol_fact = fvalid
     for pr in probes:
@@ -107,7 +108,7 @@ def _mr1_volumes(routed_fact, routed_dims, domains: Tuple[int, ...],
         for j in range(m):
             if j != i:
                 others = others * probes[j]
-        contrib = jnp.zeros((domains[i],), acc).at[fkeys[:, i]].add(
+        contrib = jnp.zeros((domains[i],), acc).at[fkeys[i]].add(
             others, mode="drop")
         (dtext, dkeys, dmask) = routed_dims[i]
         dim_vols.append(contrib[dkeys] * dmask.astype(acc))
@@ -149,14 +150,11 @@ def _device_fct(fact, dims, *, domains: Tuple[int, ...], vocab: int,
 
 
 def _plan_to_arrays(plan: CNPlan):
-    fact = {"text": jnp.asarray(plan.fact.text),
-            "keys": jnp.asarray(plan.fact.keys),
-            "send": jnp.asarray(plan.fact.send)}
-    dims = [{"text": jnp.asarray(plan.dims[i].text),
-             "keys": jnp.asarray(plan.dims[i].keys),
-             "send": jnp.asarray(plan.dims[i].send)}
-            for i in plan.included]
-    return fact, dims
+    def arrays(route):
+        return {"text": jnp.asarray(lane_major(route.text)),
+                "keys": jnp.asarray(lane_major(route.keys)),
+                "send": jnp.asarray(route.send)}
+    return arrays(plan.fact), [arrays(plan.dims[i]) for i in plan.included]
 
 
 def make_fct_program(plan: CNPlan, mesh: Mesh, histogram_backend: str = "auto"):
@@ -175,7 +173,7 @@ def make_fct_program(plan: CNPlan, mesh: Mesh, histogram_backend: str = "auto"):
         mesh=mesh,
         in_specs=(specs_rel, [specs_rel] * len(dims)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn, (fact, dims)
 
@@ -250,7 +248,7 @@ def run_cn_plan_two_jobs(plan: CNPlan, mesh: Mesh,
                 [{k: jnp.squeeze(v, 0) for k, v in d.items()} for d in ds],
                 domains=domains),
             mesh=mesh, in_specs=(specs_rel, [specs_rel] * m),
-            out_specs=vol_spec, check_rep=False))
+            out_specs=vol_spec, check_vma=False))
     vol_arrays = job1(fact, dims)
     if checkpoint_dir is not None:  # the MR boundary the paper spills to DFS
         from repro.distributed.checkpoint import (restore_checkpoint,
@@ -263,7 +261,7 @@ def run_cn_plan_two_jobs(plan: CNPlan, mesh: Mesh,
         lambda: shard_map(
             lambda va: _device_job2(va, vocab=plan.vocab_size,
                                     histogram_backend=histogram_backend),
-            mesh=mesh, in_specs=(vol_spec,), out_specs=P(), check_rep=False))
+            mesh=mesh, in_specs=(vol_spec,), out_specs=P(), check_vma=False))
     freq = job2(vol_arrays)
     return np.asarray(freq, np.int64)
 

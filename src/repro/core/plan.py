@@ -40,6 +40,18 @@ from repro.core.star import cn_volume_mass
 from repro.data.schema import PAD_ID, StarSchema
 
 
+def lane_major(arr: np.ndarray) -> np.ndarray:
+    """Host shards ``[P, S, X]`` -> the DEVICE column layout ``[P, X, S]``.
+
+    Device programs keep a relation's rows on the last (minor) axis: TPU
+    memory tiles the minor axis to 128 lanes, so a row-major ``[S, 8]``
+    int32 text column would occupy 16x its size in HBM, while ``[8, S]`` is
+    dense.  ``[P, S]`` key columns pass through unchanged."""
+    if arr.ndim == 2:
+        return arr
+    return np.ascontiguousarray(np.swapaxes(arr, 1, 2))
+
+
 def _shard_rows(arr: np.ndarray, P: int, pad_value: int) -> np.ndarray:
     rows = arr.shape[0]
     S = max(1, math.ceil(rows / P))
@@ -150,10 +162,11 @@ class RelationRef:
 
     def store_columns(self, rows_pad: int,
                       text_pad: int) -> Tuple[np.ndarray, np.ndarray]:
-        """(text, keys) host arrays padded for a RelationStore upload.
+        """(text, keys) host arrays padded for a RelationStore upload, in
+        the lane-major device layout (:func:`lane_major`).
 
-        Text is padded to ``[P, rows_pad, text_pad]`` with PAD_ID; keys are
-        FULL-width for the fact (``[P, rows_pad, m_all]`` — the engine's
+        Text is padded to ``[P, text_pad, rows_pad]`` with PAD_ID; keys are
+        FULL-width for the fact (``[P, m_all, rows_pad]`` — the engine's
         device program selects each CN's columns with a small gathered
         index, so one upload serves every CN over this tuple set) and
         ``[P, rows_pad]`` for a dim.  Padded rows are never named by any
@@ -170,7 +183,7 @@ class RelationRef:
         else:
             keys = np.pad(self.dim_key_shards(),
                           ((0, 0), (0, rows_pad - S)), constant_values=0)
-        return text, keys
+        return lane_major(text), lane_major(keys)
 
 
 @dataclasses.dataclass

@@ -1,14 +1,26 @@
-"""Pallas TPU kernels: weighted token histogram via one-hot MXU matmul.
+"""Pallas TPU kernels: weighted token histogram via a count-matrix MXU matmul.
 
 Hardware adaptation (see ``src/repro/kernels/README.md`` for the full
 design): the GPU/CPU instinct for a histogram is scatter-add; TPUs have no
 fast vector scatter, but the MXU turns the same reduction into a matmul:
 
-    hist[v0:v0+VB] += wᵀ · one_hot(tokens_block)[·, v0:v0+VB]
+    counts[v, r] = #{l : tokens[l, r] == v0 + v}        (VPU, [VB, NB])
+    hist[v0:v0+VB] += w[NB] · countsᵀ                    (MXU)
 
 Grid = (vocab_blocks, token_blocks); the token axis is the inner (fastest)
 grid dimension, so each vocab tile of the output stays resident in VMEM while
 every token block streams through — one output write per vocab tile.
+
+Layout: tokens are LANE-MAJOR, ``[L, N]`` — one row per token position,
+rows of the relation along the 128-wide lane axis.  The row-major ``[N, L]``
+layout pads its minor dimension (L = 8..16) to 128 lanes in TPU memory, a
+8-16x blow-up of every text column in HBM; lane-major text is dense.  Inside
+the kernel every block is 2-D and nothing reshapes or transposes across
+the lane axis: the count matrix is built in a static loop over the ``L``
+token rows, each ``[1, NB]`` row compared against a sublane iota
+``[VB, NB]``; weights arrive lane-major too (``[1, NB]`` / limbs
+``[K, NB]``), so the contraction over rows is one ``[rows, NB] · [VB, NB]ᵀ``
+matmul; the accumulator is written row by row.
 
 Two accumulator schemes share that layout:
 
@@ -20,24 +32,29 @@ Two accumulator schemes share that layout:
     The paper's MR² is pure integer counting, so this is the serving path.
     Each weight is split OUTSIDE the kernel into ``K`` limbs of
     ``limb_bits`` bits (``limb_bits`` chosen so a limb's partial matmul over
-    the whole contraction dimension stays < 2^24 and is therefore exact in
-    float32); inside the kernel one ``[K, NB·L] @ [NB·L, VB]`` MXU matmul
-    produces every limb's tile contribution at once, which is cast to int32
-    and added into a ``[K, VB]`` int32 accumulator.  After every step the
-    carries are propagated (``acc[k] >> limb_bits`` into ``acc[k+1]``), so
-    every non-top limb stays < 2^limb_bits and can never wrap; the top limb
-    may wrap, but only in multiples of ``2^(32 + limb_bits·(K-1))`` of the
-    recombined value, which vanish modulo the output width (ops.py picks
+    the whole contraction — at most ``NB·L`` token occurrences — stays
+    < 2^24 and is therefore exact in float32); inside the kernel one
+    ``[K, NB] · [VB, NB]ᵀ`` MXU matmul produces every limb's tile
+    contribution at once, which is cast to int32 and added into a
+    ``[K, VB]`` int32 accumulator.  After every step the carries are
+    propagated (``acc[k] >> limb_bits`` into ``acc[k+1]``), so every non-top
+    limb stays < 2^limb_bits and can never wrap; the top limb may wrap, but
+    only in multiples of ``2^(32 + limb_bits·(K-1))`` of the recombined
+    value, which vanish modulo the output width (ops.py picks
     ``K = ceil(width / limb_bits)``).  The host recombines
     ``Σ acc[k] << (limb_bits·k)`` in the weights' integer dtype — making
     device accumulation bit-identical to an int32/int64 host accumulation,
     wrap-around included.
 
-VMEM working set per step: NB·L·4 (tokens) + NB·K·4 (limbs) + K·VB·4
-(accumulator) + NB·L·VB·4 transient one-hot; with NB·L = 1024, VB = 512,
-K ≤ 6 that is ~2.2 MB, comfortably under the ~16 MB/core budget, and the
-matmul contraction dimension (NB·L = 1024) and output tile (VB = 512) are
-MXU-aligned (multiples of 128).
+Exactness of the float32 matmul: counts are integers <= L, exact in
+bfloat16, so the ``Precision.HIGHEST`` multi-pass product ``w · count`` is
+exact for any float32 ``w`` (every dropped cross term multiplies a zero low
+part of the count); partial sums stay exact below 2^24.
+
+VMEM working set per step: L·NB·4 (tokens) + K·NB·4 (limbs) + K·VB·4
+(accumulator) + VB·NB·4 iota + VB·NB·4 counts; with NB = 128, VB = 512,
+K ≤ 6 that is ~0.5 MB, far under the scoped-VMEM budget, and the matmul
+contraction (NB = 128) and output tile (VB = 512) are MXU-aligned.
 """
 from __future__ import annotations
 
@@ -68,31 +85,43 @@ def limb_split(contraction: int, acc_bits: int):
     return limb_bits, -(-acc_bits // limb_bits)
 
 
+def _token_counts(tokens_ref, vocab_block: int):
+    """counts[v, r] = occurrences of term ``v0 + v`` in token row ``r``
+    (PAD never counts), float32 ``[VB, NB]`` for this grid step's vocab
+    tile.  One sublane-broadcast compare per token position: no reshape of
+    the token block."""
+    tl, nb = tokens_ref.shape
+    v0 = pl.program_id(0) * vocab_block
+    tok = tokens_ref[...]
+    tok = jnp.where(tok == PAD_ID, -1, tok)   # -1 matches no vocab id
+    vocab_ids = v0 + jax.lax.broadcasted_iota(jnp.int32, (vocab_block, nb), 0)
+    counts = jnp.zeros((vocab_block, nb), jnp.float32)
+    for pos in range(tl):
+        counts = counts + (tok[pos:pos + 1, :] == vocab_ids).astype(
+            jnp.float32)
+    return counts
+
+
+def _rows_dot_counts(rows, counts):
+    """``rows [R, NB] · counts [VB, NB]ᵀ -> [R, VB]`` on the MXU.  HIGHEST
+    is load-bearing: the default TPU matmul runs one bfloat16 pass, whose
+    8-bit mantissa cannot even represent a limb value."""
+    return jax.lax.dot_general(rows, counts, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
+
+
 # ---------------------------------------------------------------------------
 # float32-accumulator kernel (floating-point weights only)
 # ---------------------------------------------------------------------------
 
 def _fct_count_kernel(tokens_ref, weights_ref, hist_ref, *, vocab_block: int):
-    nb, tl = tokens_ref.shape
-    v0 = pl.program_id(0) * vocab_block
-
     @pl.when(pl.program_id(1) == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    tok = tokens_ref[...].reshape(nb * tl)
-    # broadcast-reshape, not jnp.repeat: no materialized gather on the VPU
-    w = jnp.broadcast_to(weights_ref[...][:, None], (nb, tl))
-    w = w.reshape(nb * tl).astype(jnp.float32)
-    w = jnp.where(tok == PAD_ID, 0.0, w)
-    vocab_ids = v0 + jax.lax.broadcasted_iota(jnp.int32, (nb * tl, vocab_block), 1)
-    onehot = (tok[:, None] == vocab_ids).astype(jnp.float32)
-    # [1, NB*L] @ [NB*L, VB] on the MXU; HIGHEST forbids the default
-    # bfloat16-pass lowering, which would break the < 2^24 exactness claim
-    contrib = jnp.dot(w[None, :], onehot,
-                      preferred_element_type=jnp.float32,
-                      precision=jax.lax.Precision.HIGHEST)[0]
-    hist_ref[...] += contrib
+    hist_ref[...] += _rows_dot_counts(weights_ref[...],
+                                      _token_counts(tokens_ref, vocab_block))
 
 
 @functools.partial(jax.jit, static_argnames=("vocab", "token_block",
@@ -101,26 +130,27 @@ def fct_count_pallas(tokens: jnp.ndarray, weights: jnp.ndarray, vocab: int,
                      token_block: int = DEFAULT_TOKEN_BLOCK,
                      vocab_block: int = DEFAULT_VOCAB_BLOCK,
                      interpret: bool = False) -> jnp.ndarray:
-    """tokens [N, L] int32 (N % token_block == 0, vocab % vocab_block == 0).
+    """tokens [L, N] int32, lane-major (N % token_block == 0,
+    vocab % vocab_block == 0); weights [N].
 
     float32 accumulation: exact only for totals < 2^24.  Integer weights
     should use :func:`fct_count_pallas_exact` (ops.py dispatches).
     """
-    n, tl = tokens.shape
+    tl, n = tokens.shape
     assert n % token_block == 0 and vocab % vocab_block == 0
     grid = (vocab // vocab_block, n // token_block)
     out = pl.pallas_call(
         functools.partial(_fct_count_kernel, vocab_block=vocab_block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((token_block, tl), lambda i, j: (j, 0)),
-            pl.BlockSpec((token_block,), lambda i, j: (j,)),
+            pl.BlockSpec((tl, token_block), lambda i, j: (0, j)),
+            pl.BlockSpec((1, token_block), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((vocab_block,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((vocab,), jnp.float32),
+        out_specs=pl.BlockSpec((1, vocab_block), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, vocab), jnp.float32),
         interpret=interpret,
-    )(tokens, weights.astype(jnp.float32))
-    return out.at[PAD_ID].set(0.0)
+    )(tokens, weights.astype(jnp.float32)[None, :])
+    return out[0].at[PAD_ID].set(0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -129,31 +159,20 @@ def fct_count_pallas(tokens: jnp.ndarray, weights: jnp.ndarray, vocab: int,
 
 def _fct_count_exact_kernel(tokens_ref, limbs_ref, acc_ref, *,
                             vocab_block: int, limb_bits: int):
-    nb, tl = tokens_ref.shape
-    n_limbs = limbs_ref.shape[1]
-    v0 = pl.program_id(0) * vocab_block
+    n_limbs = limbs_ref.shape[0]
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tok = tokens_ref[...].reshape(nb * tl)
-    valid = (tok != PAD_ID).astype(jnp.float32)
-    vocab_ids = v0 + jax.lax.broadcasted_iota(jnp.int32, (nb * tl, vocab_block), 1)
-    onehot = (tok[:, None] == vocab_ids).astype(jnp.float32)
-    # limbs [NB, K] -> [K, NB*L] (broadcast-reshape per row, PAD masked);
-    # each row holds one limb of every token's weight, all < 2^limb_bits
-    limbs = limbs_ref[...].astype(jnp.float32).T
-    limbs = jnp.broadcast_to(limbs[:, :, None], (n_limbs, nb, tl))
-    limbs = limbs.reshape(n_limbs, nb * tl) * valid[None, :]
-    # [K, NB*L] @ [NB*L, VB] on the MXU: every limb's tile contribution in
-    # one matmul; each partial sum < 2^limb_bits * NB*L <= 2^24, so the
-    # float32 result is an exact integer and the int32 cast is lossless.
-    # HIGHEST is load-bearing: the default TPU matmul runs bfloat16 passes,
-    # whose 8-bit mantissa cannot even represent a limb value
-    contrib = jnp.dot(limbs, onehot, preferred_element_type=jnp.float32,
-                      precision=jax.lax.Precision.HIGHEST)
-    acc = [acc_ref[k, :] + contrib[k].astype(jnp.int32)
+    # every limb's tile contribution in one matmul; each limb row holds one
+    # limb of every row's weight (all < 2^limb_bits) and a count is a row's
+    # occurrences of the term, so each partial sum < 2^limb_bits * NB*L
+    # <= 2^24: the float32 result is an exact integer and the int32 cast is
+    # lossless
+    contrib = _rows_dot_counts(limbs_ref[...].astype(jnp.float32),
+                               _token_counts(tokens_ref, vocab_block))
+    acc = [acc_ref[k:k + 1, :] + contrib[k:k + 1, :].astype(jnp.int32)
            for k in range(n_limbs)]
     # carry propagation on every step keeps each non-top limb < 2^limb_bits
     # (so it can never wrap int32); only the top limb may wrap, harmlessly
@@ -162,7 +181,8 @@ def _fct_count_exact_kernel(tokens_ref, limbs_ref, acc_ref, *,
         carry = acc[k] >> limb_bits
         acc[k] = acc[k] - (carry << limb_bits)
         acc[k + 1] = acc[k + 1] + carry
-    acc_ref[...] = jnp.stack(acc)
+    for k in range(n_limbs):
+        acc_ref[k:k + 1, :] = acc[k]
 
 
 @functools.partial(jax.jit, static_argnames=("vocab", "token_block",
@@ -172,14 +192,15 @@ def fct_count_pallas_exact(tokens: jnp.ndarray, weights: jnp.ndarray,
                            token_block: int = DEFAULT_TOKEN_BLOCK,
                            vocab_block: int = DEFAULT_VOCAB_BLOCK,
                            interpret: bool = False) -> jnp.ndarray:
-    """Integer-exact weighted histogram; tokens [N, L] int32, weights [N] int.
+    """Integer-exact weighted histogram; tokens [L, N] int32 (lane-major),
+    weights [N] int.
 
     Returns totals in the weights' dtype, bit-identical to the ref path's
     host-style accumulation (exact modulo 2^32 for int32 weights, modulo
     2^64 for int64) — including wrap-around, so the engine's int32 overflow
     check sees exactly what a plain int32 accumulation would have produced.
     """
-    n, tl = tokens.shape
+    tl, n = tokens.shape
     assert n % token_block == 0 and vocab % vocab_block == 0
     assert jnp.issubdtype(weights.dtype, jnp.integer), weights.dtype
     # exactness is modulo the weight dtype's full width (int16/uint64/...
@@ -188,19 +209,20 @@ def fct_count_pallas_exact(tokens: jnp.ndarray, weights: jnp.ndarray,
     acc_bits = jnp.iinfo(weights.dtype).bits
     limb_bits, n_limbs = limb_split(token_block * tl, acc_bits)
     mask = (1 << limb_bits) - 1
-    # split outside the kernel: limb k holds bits [limb_bits*k, limb_bits*(k+1))
-    # of each weight's two's-complement pattern (arithmetic >> sign-extends,
-    # which keeps the mod-2^acc_bits recombination exact for negatives too)
+    # split outside the kernel, lane-major [K, N]: limb k holds bits
+    # [limb_bits*k, limb_bits*(k+1)) of each weight's two's-complement
+    # pattern (arithmetic >> sign-extends, which keeps the mod-2^acc_bits
+    # recombination exact for negatives too)
     limbs = jnp.stack([(weights >> (limb_bits * k)) & mask
-                       for k in range(n_limbs)], axis=1).astype(jnp.int32)
+                       for k in range(n_limbs)]).astype(jnp.int32)
     grid = (vocab // vocab_block, n // token_block)
     acc = pl.pallas_call(
         functools.partial(_fct_count_exact_kernel, vocab_block=vocab_block,
                           limb_bits=limb_bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((token_block, tl), lambda i, j: (j, 0)),
-            pl.BlockSpec((token_block, n_limbs), lambda i, j: (j, 0)),
+            pl.BlockSpec((tl, token_block), lambda i, j: (0, j)),
+            pl.BlockSpec((n_limbs, token_block), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((n_limbs, vocab_block), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n_limbs, vocab), jnp.int32),

@@ -11,6 +11,10 @@ Backend dispatch table (see ``src/repro/kernels/README.md``):
     backend="ref"        pure-jnp segment-sum oracle (ref.py), any dtype
     backend="interpret"  legacy spelling of backend="pallas", interpret=True
 
+Tokens are LANE-MAJOR, ``[L, N]``: one row per token position, the
+relation's rows along the minor axis (the row-major ``[N, L]`` layout pads
+its narrow minor axis to 128 lanes in TPU memory; see kernel.py).
+
 ``interpret=True`` executes the selected Pallas kernel body through the
 Pallas interpreter (CPU) — how tests and the CI x64 job drive the kernel
 without a TPU.  int64 weights (the engine's INT64_EXACT accumulation
@@ -42,21 +46,22 @@ def reset_path_counts() -> None:
 
 
 def _pad_to(x: jnp.ndarray, multiple: int, value) -> jnp.ndarray:
-    n = x.shape[0]
-    pad = (-n) % multiple
+    """Pad the LAST axis (the rows) up to a multiple."""
+    pad = (-x.shape[-1]) % multiple
     if pad == 0:
         return x
-    cfg = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
+    cfg = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
     return jnp.pad(x, cfg, constant_values=value)
 
 
 def weighted_histogram(tokens: jnp.ndarray, weights: jnp.ndarray, vocab: int,
                        backend: str = "auto",
                        interpret: bool = False) -> jnp.ndarray:
-    """freq[w] = Σ_rows weight[row]·count(tokens[row], w); PAD excluded.
+    """freq[w] = Σ_rows weight[row]·count(tokens[:, row], w); PAD excluded.
 
-    Output dtype follows ``weights``.  Integer weights (int32, and int64
-    under ``jax_enable_x64``) take the split-limb integer-exact kernel on
+    tokens [L, N] int32 (lane-major), weights [N].  Output dtype follows
+    ``weights``.  Integer weights (int32, and int64 under
+    ``jax_enable_x64``) take the split-limb integer-exact kernel on
     the pallas path: totals are bit-identical to the ref path's integer
     accumulation — wrap-around included, so the runtime's AccumPolicy
     overflow check behaves the same on every backend.  Floating weights

@@ -31,8 +31,8 @@ def _kernel(a_ref, b_ref, o_ref, h_scr, *, block_s: int):
         # unit dims indexed with dslice, not bare ints: the interpret-mode
         # discharge rule only accepts Slice/array indices
         idx = (pl.dslice(0, 1), pl.dslice(t, 1), slice(None))
-        h = pl.load(a_ref, idx)[0, 0] * h + pl.load(b_ref, idx)[0, 0]
-        pl.store(o_ref, idx, h[None, None])
+        h = a_ref[idx][0, 0] * h + b_ref[idx][0, 0]
+        o_ref[idx] = h[None, None]
         return h
 
     h_scr[...] = lax.fori_loop(0, block_s, step, h_scr[...])

@@ -34,6 +34,8 @@ def main():
                          "JSON (chrome://tracing / Perfetto)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from examples.quickstart import TOK, build_db
     from repro.api import FCTRequest, FCTSession
     from repro.obs import write_chrome_trace

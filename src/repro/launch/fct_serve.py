@@ -104,6 +104,8 @@ def main() -> None:
                     metavar="S", help="seconds between --metrics-out lines")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from examples.quickstart import TOK, build_db
     from repro.api import FCTRequest
     from repro.data.tpch import TpchConfig

@@ -117,7 +117,7 @@ def apply_moe(x, p, cfg):
 def _apply_moe_shardmap(x, p, cfg):
     import math as _math
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _ACT_CTX["mesh"]
@@ -189,7 +189,7 @@ def _apply_moe_shardmap(x, p, cfg):
         in_specs=(P(dp, None), P(None, None),
                   P(ep, None, None), P(ep, None, None), P(ep, None, None)),
         out_specs=(P(dp, None), P()),
-        check_rep=False)
+        check_vma=False)
     yt, aux = fn(x.reshape(t, d), p["router"].astype(jnp.float32),
                  p["w_gate"], p["w_up"], p["w_down"])
     xt = x.reshape(t, d)

@@ -33,7 +33,7 @@ import jax
 import numpy as np
 
 from repro.core.accum import INT32_CHECKED, AccumPolicy
-from repro.core.plan import CNPlan, RelationRoute
+from repro.core.plan import CNPlan, RelationRoute, lane_major
 from repro.data.schema import PAD_ID
 
 BUCKET_MIN = 8
@@ -55,12 +55,13 @@ def bucket_pow2(n: int, minimum: int = BUCKET_MIN) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class RelationSig:
-    """Padded dims of one routed relation: [P, rows, text_len] text,
-    [P, P, cap] send table, key domain (0 for the fact side).
+    """Padded dims of one routed relation: [P, text_len, rows] text
+    (lane-major, see ``repro.core.plan.lane_major``), [P, P, cap] send
+    table, key domain (0 for the fact side).
 
     ``key_width`` is the fact relation's FULL key-column count (0 for dims):
     the store-path device program takes the full-width stored key matrix
-    [P, rows, key_width] plus a per-CN column-index gather, so its shapes —
+    [P, key_width, rows] plus a per-CN column-index gather, so its shapes —
     and hence the executable-cache key — depend on it."""
 
     rows: int
@@ -126,6 +127,8 @@ def plan_signature(plan: CNPlan, bucket: bool = True,
 
 
 def _pad_route(route: RelationRoute, sig: RelationSig) -> Dict[str, np.ndarray]:
+    """One relation's padded device arguments, columns lane-major
+    (``text [P, L, S]``, keys ``[P, m, S]`` / ``[P, S]``)."""
     rtext, rkeys = route.text, route.keys   # materialize the lazy columns once
     P, S, L = rtext.shape
     text = np.pad(rtext, ((0, 0), (0, sig.rows - S), (0, sig.text_len - L)),
@@ -134,7 +137,7 @@ def _pad_route(route: RelationRoute, sig: RelationSig) -> Dict[str, np.ndarray]:
     keys = np.pad(rkeys, key_pad, constant_values=0)
     send = np.pad(route.send, ((0, 0), (0, 0), (0, sig.cap - route.send.shape[-1])),
                   constant_values=-1)
-    return {"text": text, "keys": keys, "send": send}
+    return {"text": lane_major(text), "keys": lane_major(keys), "send": send}
 
 
 def pad_plan_arrays(plan: CNPlan, sig: PlanSignature):

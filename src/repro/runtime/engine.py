@@ -53,8 +53,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.accum import AccumPolicy
@@ -166,7 +165,7 @@ def _build_batched_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
 
     return shard_map(device_fn, mesh=mesh, in_specs=(spec, [spec] * sig.m),
                      out_specs=_out_spec(reduce_cns, reduce_scatter),
-                     check_rep=False)
+                     check_vma=False)
 
 
 def _build_store_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
@@ -208,7 +207,7 @@ def _build_store_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
     return shard_map(device_fn, mesh=mesh,
                      in_specs=(fact_spec, [rel_spec] * sig.m),
                      out_specs=_out_spec(reduce_cns, reduce_scatter),
-                     check_rep=False)
+                     check_vma=False)
 
 
 def topk_signature(vocab: int, n_devices: int, accum: AccumPolicy,
@@ -301,7 +300,7 @@ def _build_topk_fn(sig: PlanSignature, mesh: Mesh, reduce_scatter: bool,
     hist_spec = P("w") if reduce_scatter else P()
     return shard_map(device_fn, mesh=mesh,
                      in_specs=(hist_spec, P(), hist_spec),
-                     out_specs=(P(), P(), P()), check_rep=False)
+                     out_specs=(P(), P(), P()), check_vma=False)
 
 
 @dataclasses.dataclass

@@ -49,8 +49,8 @@ from repro.runtime.cache import LruDict
 class StoredColumns(NamedTuple):
     """One tuple-set relation's device-resident padded columns."""
 
-    text: jax.Array      # [P, rows_pad, text_pad] int32, sharded P("w")
-    keys: jax.Array      # [P, rows_pad(, m_all)] int32, sharded P("w")
+    text: jax.Array      # [P, text_pad, rows_pad] int32, sharded P("w")
+    keys: jax.Array      # [P(, m_all), rows_pad] int32, sharded P("w")
     nbytes: int
 
 
@@ -191,25 +191,32 @@ class RelationStore:
         device (eager jnp ops + a resharding device_put), no host columns.
         """
         P_dev = parts[0].n_devices
-        texts, keys = [], []
-        for p, c in zip(parts, cols):
-            S, n = p.shard_rows, p.n_rows
-            texts.append(c.text[:, :S, :].reshape(P_dev * S, text_pad)[:n])
-            k = c.keys[:, :S]
-            keys.append(k.reshape((P_dev * S,) + k.shape[2:])[:n])
-        text = jnp.concatenate(texts, axis=0)
-        keyc = jnp.concatenate(keys, axis=0)
-        n_total = int(text.shape[0])
+
+        def rows_flat(a, S, n):
+            # [P, *mid, rows_pad] -> [*mid, n]: each device's first S slots,
+            # devices concatenated in flat row order, flat tail pad dropped
+            a = jnp.moveaxis(a[..., :S], 0, -2)
+            return a.reshape(a.shape[:-2] + (P_dev * S,))[..., :n]
+
+        text = jnp.concatenate([rows_flat(c.text, p.shard_rows, p.n_rows)
+                                for p, c in zip(parts, cols)], axis=-1)
+        keyc = jnp.concatenate([rows_flat(c.keys, p.shard_rows, p.n_rows)
+                                for p, c in zip(parts, cols)], axis=-1)
+        n_total = int(text.shape[-1])
         S_ref = -(-n_total // P_dev)          # == the combined ref's
         #                                       shard_rows (<= rows_pad)
-        tail = P_dev * S_ref - n_total
-        text = jnp.pad(text, ((0, tail), (0, 0)), constant_values=PAD_ID)
-        keyc = jnp.pad(keyc, ((0, tail),) + ((0, 0),) * (keyc.ndim - 1))
-        text = text.reshape(P_dev, S_ref, text_pad)
-        keyc = keyc.reshape((P_dev, S_ref) + keyc.shape[1:])
-        row_pad = ((0, 0), (0, rows_pad - S_ref))
-        text = jnp.pad(text, row_pad + ((0, 0),), constant_values=PAD_ID)
-        keyc = jnp.pad(keyc, row_pad + ((0, 0),) * (keyc.ndim - 2))
+
+        def reshard(a, fill):
+            # [*mid, n_total] -> [P, *mid, rows_pad], contiguous row shards
+            tail = P_dev * S_ref - a.shape[-1]
+            a = jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, tail),),
+                        constant_values=fill)
+            a = jnp.moveaxis(a.reshape(a.shape[:-1] + (P_dev, S_ref)), -2, 0)
+            return jnp.pad(a, ((0, 0),) * (a.ndim - 1)
+                           + ((0, rows_pad - S_ref),), constant_values=fill)
+
+        text = reshard(text, PAD_ID)
+        keyc = reshard(keyc, 0)
         return StoredColumns(
             text=jax.device_put(text, self._sharding),
             keys=jax.device_put(keyc, self._sharding),

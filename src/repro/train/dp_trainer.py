@@ -13,7 +13,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -51,7 +51,7 @@ def make_compressed_dp_step(cfg: ArchConfig, mesh: Mesh, axis: str = "data",
     fn = shard_map(local_step, mesh=mesh,
                    in_specs=(rep, rep, rep, batch_spec),
                    out_specs=(rep, rep, rep, rep),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)
 
 

@@ -56,7 +56,7 @@ def test_r1_allows_jit_in_runtime_and_kernels(tmp_path):
 def test_r1_flags_decorator_and_shard_map(tmp_path):
     violations, _ = run_rules(tmp_path, "api/foo.py", """\
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         @jax.jit
         def f(x):

@@ -132,7 +132,7 @@ def test_weighted_histogram_exact_across_precision_boundaries(data):
     boundary = data.draw(st.sampled_from(
         [0, 1, (1 << 24) - 1, 1 << 24, (1 << 24) + 1, hi]))
     rng = np.random.default_rng(data.draw(st.integers(0, 10_000)))
-    toks = jnp.asarray(rng.integers(1, vocab, (n, tl)), jnp.int32)
+    toks = jnp.asarray(rng.integers(1, vocab, (tl, n)), jnp.int32)  # [L, N]
     w = np.minimum(rng.integers(0, max(boundary, 2), (n,)), hi)
     w = jnp.asarray(w).astype(wdtype)
     r = np.asarray(fct_ref.weighted_histogram(toks, w, vocab))
@@ -140,7 +140,7 @@ def test_weighted_histogram_exact_across_precision_boundaries(data):
                                       interpret=True))
     np.testing.assert_array_equal(r, k)
     np.testing.assert_array_equal(
-        tokens_histogram(np.asarray(toks), np.asarray(w), vocab),
+        tokens_histogram(np.asarray(toks).T, np.asarray(w), vocab),
         k.astype(np.int64))
 
 

@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.launch.hlo_analysis import analyze_text
@@ -51,7 +51,7 @@ def test_collectives_counted_with_ring_model():
         return jax.lax.psum(x, "w")
 
     fn = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     txt = jax.jit(fn).lower(jnp.zeros((16, 16), jnp.float32)) \
         .compile().as_text()
     tot = analyze_text(txt)

@@ -22,7 +22,7 @@ RNG = np.random.default_rng(0)
 ])
 @pytest.mark.parametrize("wdtype", [jnp.int32, jnp.float32])
 def test_fct_count_matches_ref(n, tl, vocab, wdtype):
-    toks = jnp.asarray(RNG.integers(0, vocab, (n, tl)), jnp.int32)
+    toks = jnp.asarray(RNG.integers(0, vocab, (tl, n)), jnp.int32)
     w = jnp.asarray(RNG.integers(0, 9, (n,))).astype(wdtype)
     r = fct_ref.weighted_histogram(toks, w, vocab)
     k = weighted_histogram(toks, w, vocab, backend="interpret")
@@ -31,7 +31,7 @@ def test_fct_count_matches_ref(n, tl, vocab, wdtype):
 
 
 def test_fct_count_pad_never_counted():
-    toks = jnp.zeros((16, 4), jnp.int32)  # all PAD
+    toks = jnp.zeros((4, 16), jnp.int32)  # all PAD
     w = jnp.ones((16,), jnp.int32)
     out = weighted_histogram(toks, w, 64, backend="interpret")
     assert int(jnp.sum(jnp.abs(out))) == 0
@@ -40,16 +40,17 @@ def test_fct_count_pad_never_counted():
 # --- fct_count integer-exact accumulator (split-limb kernel) -----------------
 
 def _np_hist(toks, w, vocab):
-    """Seed-style numpy oracle: int64 accumulation, PAD excluded."""
+    """Seed-style numpy oracle: int64 accumulation, PAD excluded (it takes
+    row-major [N, L] tokens; the op takes lane-major [L, N])."""
     from repro.data.schema import tokens_histogram
-    return tokens_histogram(np.asarray(toks), np.asarray(w), vocab)
+    return tokens_histogram(np.asarray(toks).T, np.asarray(w), vocab)
 
 
 def test_fct_count_exact_across_2_24_boundary():
     # odd-valued totals past 2^24: the old float32 accumulator rounded here
     # (increments below the float spacing), the split-limb kernel must be
     # bit-identical to the integer ref AND the seed numpy oracle
-    toks = jnp.asarray(RNG.integers(1, 16, (512, 5)), jnp.int32)
+    toks = jnp.asarray(RNG.integers(1, 16, (5, 512)), jnp.int32)
     w = jnp.asarray(RNG.integers(0, 1 << 19, (512,)), jnp.int32)
     r = np.asarray(fct_ref.weighted_histogram(toks, w, 100))
     k = np.asarray(weighted_histogram(toks, w, 100, backend="pallas",
@@ -64,7 +65,7 @@ def test_fct_count_exact_wraps_int32_like_ref():
     # past 2^31 the int32 contract is wrap-around (the engine's
     # INT32_CHECKED policy detects it on collection); kernel and ref must
     # wrap to the SAME bit pattern, negatives included
-    toks = jnp.full((24, 1), 7, jnp.int32)
+    toks = jnp.full((1, 24), 7, jnp.int32)
     w = jnp.full((24,), (1 << 27) + 12345, jnp.int32)  # total ~3.2e9 > 2^31
     r = np.asarray(fct_ref.weighted_histogram(toks, w, 64))
     k = np.asarray(weighted_histogram(toks, w, 64, backend="pallas",
@@ -76,7 +77,7 @@ def test_fct_count_exact_wraps_int32_like_ref():
 def test_fct_count_exact_carry_propagation_across_token_blocks():
     # many token blocks, weights spanning all limbs: exercises the per-step
     # carry chain (non-top limbs must never wrap while blocks stream)
-    toks = jnp.asarray(RNG.integers(1, 8, (1024, 4)), jnp.int32)
+    toks = jnp.asarray(RNG.integers(1, 8, (4, 1024)), jnp.int32)
     w = jnp.asarray(RNG.integers(0, 1 << 14, (1024,)), jnp.int32)
     r = np.asarray(fct_ref.weighted_histogram(toks, w, 64))
     k = np.asarray(weighted_histogram(toks, w, 64, backend="pallas",
@@ -91,7 +92,7 @@ def test_fct_count_exact_int64_across_2_31_boundary():
     # the retired behavior forced int64 weights onto the ref path; now they
     # ride the exact kernel: weights individually past 2^31, totals past
     # 2^33, all bit-identical to the int64 ref and the seed oracle
-    toks = jnp.asarray(RNG.integers(1, 50, (300, 3)), jnp.int32)
+    toks = jnp.asarray(RNG.integers(1, 50, (3, 300)), jnp.int32)
     w = jnp.asarray(RNG.integers((1 << 31) - 4, (1 << 35), (300,)), jnp.int64)
     r = np.asarray(fct_ref.weighted_histogram(toks, w, 128))
     k = np.asarray(weighted_histogram(toks, w, 128, backend="pallas",
@@ -107,7 +108,7 @@ def test_fct_count_exact_int64_across_2_31_boundary():
 def test_fct_count_exact_int64_full_range_wrap_parity():
     # weights near 2^62: totals wrap mod 2^64 — kernel and ref must agree
     # bit for bit even there (the split covers the full 64-bit width)
-    toks = jnp.asarray(RNG.integers(1, 30, (257, 3)), jnp.int32)
+    toks = jnp.asarray(RNG.integers(1, 30, (3, 257)), jnp.int32)
     w = jnp.asarray(RNG.integers(1 << 61, 1 << 62, (257,)), jnp.int64)
     r = np.asarray(fct_ref.weighted_histogram(toks, w, 64))
     k = np.asarray(weighted_histogram(toks, w, 64, backend="pallas",
@@ -121,7 +122,7 @@ def test_fct_count_exact_covers_every_integer_width(wdtype, hi):
     # ops routes EVERY integer dtype here: the limb count and recombination
     # must follow the dtype's actual width (exact modulo 2^bits), not
     # assume int32/int64
-    toks = jnp.asarray(RNG.integers(1, 16, (96, 3)), jnp.int32)
+    toks = jnp.asarray(RNG.integers(1, 16, (3, 96)), jnp.int32)
     w = jnp.asarray(RNG.integers(0, hi, (96,))).astype(wdtype)
     r = np.asarray(fct_ref.weighted_histogram(toks, w, 64))
     k = np.asarray(weighted_histogram(toks, w, 64, backend="pallas",
@@ -132,7 +133,7 @@ def test_fct_count_exact_covers_every_integer_width(wdtype, hi):
 
 def test_fct_count_backend_dispatch_paths():
     from repro.kernels.fct_count import ops
-    toks = jnp.asarray(RNG.integers(1, 16, (8, 2)), jnp.int32)
+    toks = jnp.asarray(RNG.integers(1, 16, (2, 8)), jnp.int32)
     w_int = jnp.ones((8,), jnp.int32)
     w_float = jnp.ones((8,), jnp.float32)
     ops.reset_path_counts()

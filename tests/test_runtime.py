@@ -77,7 +77,7 @@ def test_quantize_roundtrip_error_bound():
 def test_compressed_psum_error_feedback_converges():
     """Over repeated steps with constant gradient, error feedback makes the
     AVERAGE applied gradient converge to the true one."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
     g_true = {"w": jnp.asarray(
@@ -87,7 +87,7 @@ def test_compressed_psum_error_feedback_converges():
         err = {"w": err_leaf}
         fn = shard_map(lambda e: compressed_psum(g_true, {"w": e}, "dp"),
                        mesh=mesh, in_specs=P(), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
         mean, new_err = fn(err["w"])
         return mean, new_err
 

@@ -9,7 +9,7 @@ from repro.api import FCTRequest, FCTSession, SessionConfig
 from repro.core.candidate_network import (TupleSets, enumerate_star_cns,
                                           prune_empty_cns)
 from repro.core.fct import run_cn_plan
-from repro.core.plan import build_cn_plan
+from repro.core.plan import build_cn_plan, lane_major
 from repro.core.star import fct_star
 from repro.launch.mesh import make_worker_mesh
 from repro.runtime.engine import FCTEngine
@@ -212,10 +212,12 @@ def test_plans_are_descriptors_not_copies():
         "plan copied the fact text"
     for i, route in plan.dims.items():
         assert route.ref.base_text is schema.dims[i].text
-    # materialized legacy columns agree with the store-upload layout
+    # materialized legacy columns agree with the store-upload layout (the
+    # store keeps them lane-major: rows on the last axis)
     text, keys = plan.fact.ref.store_columns(
         plan.fact.ref.shard_rows, plan.fact.ref.text_len)
-    np.testing.assert_array_equal(text, plan.fact.text)
+    np.testing.assert_array_equal(text, lane_major(plan.fact.text))
     sel = plan.fact.ref.fact_key_shards(plan.fact.key_cols)
     np.testing.assert_array_equal(sel, plan.fact.keys)
-    np.testing.assert_array_equal(keys[..., list(plan.fact.key_cols)], sel)
+    np.testing.assert_array_equal(keys[:, list(plan.fact.key_cols)],
+                                  lane_major(sel))
