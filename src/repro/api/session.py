@@ -45,7 +45,7 @@ from repro.core.plan import CNPlan, build_cn_plan
 from repro.core.star import topk_terms
 from repro.data.schema import (PAD_ID, StarSchema, keyword_mask,
                                tokens_histogram)
-from repro.obs import Trace, default_registry, maybe_activate
+from repro.obs import Trace, annotate, default_registry, maybe_activate
 from repro.obs import span as obs_span
 from repro.runtime.cache import LruDict
 from repro.runtime.store import RelationStore
@@ -565,7 +565,9 @@ class FCTSession:
             all_plans.extend(p.plans)
         t0 = time.perf_counter()
         t0_ns = time.perf_counter_ns()
-        with self._engine_lock:
+        # the dispatch span is added to every trace of the flight below; one
+        # annotation of the same name puts it on the profiler's clock once
+        with annotate("dispatch"), self._engine_lock:
             before = self._engine_snapshot()
             pending = topk = None
             if use_topk:
@@ -617,17 +619,19 @@ class FCTSession:
         t0_ns = time.perf_counter_ns()
         vocab = self.schema.vocab_size
         per_plan = total = topk_ids = topk_counts = None
-        if flight.topk is not None:
-            topk_ids, topk_counts = self.engine.collect_topk(flight.topk)
-        elif flight.pending is not None:
-            if flight.individual:
-                per_plan = self.engine.collect_individual(
-                    flight.pending, flight.n_plans, vocab)
-            else:
-                total = self.engine.collect_total(flight.pending, vocab)
-        # the counter delta is taken after collection so the transfer-side
-        # counters (device_to_host_bytes) land in this query's stats
-        delta = self._engine_delta(flight.engine_before)
+        with annotate("collect"):     # recorded per trace below, as dispatch
+            if flight.topk is not None:
+                topk_ids, topk_counts = self.engine.collect_topk(flight.topk)
+            elif flight.pending is not None:
+                if flight.individual:
+                    per_plan = self.engine.collect_individual(
+                        flight.pending, flight.n_plans, vocab)
+                else:
+                    total = self.engine.collect_total(flight.pending, vocab)
+            # the counter delta is taken after collection so the
+            # transfer-side counters (device_to_host_bytes) land in this
+            # query's stats
+            delta = self._engine_delta(flight.engine_before)
         collect_ms = (time.perf_counter() - t0) * 1e3
         dur_ns = time.perf_counter_ns() - t0_ns
         for p in flight.planned:

@@ -12,6 +12,15 @@ Theorem 1 — and a host-side top-k with the Def. 6 exclusions.
 The two jobs are separable (``job1`` returns the vol-array artifact that
 ``job2`` consumes) so the MR¹→MR² boundary can be checkpointed, but the fused
 path is the default: on a TPU there is no reason to spill the intermediate.
+
+Each stage of the device body runs under a ``jax.named_scope``, so every op's
+HLO ``op_name`` (and a profile's op metadata) names its stage: ``fct.stack``
+(CN slots and the fact's key-column select), ``fct.route`` (send-table
+gathers, masks), ``fct.mr1`` (num-arrays, probes, volumes), ``fct.mr2``
+(histogram inputs and the ``fct_count`` calls), ``fct.reduce`` (cross-CN
+sum, accumulator casts, vocab pads), ``fct.topk`` (the finalize program) and
+``fct.collective`` (every cross-device collective, scoped at its call site
+so it is the innermost scope of the op).
 """
 from __future__ import annotations
 
@@ -54,18 +63,21 @@ def _route(text, keys, send):
     keys [P*C] / [m, P*C], mask [P*C]) of received rows.
     """
     p, c = send.shape
-    idx = jnp.maximum(send, 0).reshape(-1)
-    mask = send >= 0
-    btext = jnp.take(text, idx, axis=-1).reshape(text.shape[:-1] + (p, c))
-    bkeys = jnp.take(keys, idx, axis=-1).reshape(keys.shape[:-1] + (p, c))
-    rtext = lax.all_to_all(btext, "w", split_axis=btext.ndim - 2,
-                           concat_axis=btext.ndim - 2, tiled=True)
-    rkeys = lax.all_to_all(bkeys, "w", split_axis=bkeys.ndim - 2,
-                           concat_axis=bkeys.ndim - 2, tiled=True)
-    rmask = lax.all_to_all(mask, "w", split_axis=0, concat_axis=0, tiled=True)
-    return (rtext.reshape(text.shape[:-1] + (p * c,)),
-            rkeys.reshape(keys.shape[:-1] + (p * c,)),
-            rmask.reshape(p * c))
+    with jax.named_scope("fct.route"):
+        idx = jnp.maximum(send, 0).reshape(-1)
+        mask = send >= 0
+        btext = jnp.take(text, idx, axis=-1).reshape(text.shape[:-1] + (p, c))
+        bkeys = jnp.take(keys, idx, axis=-1).reshape(keys.shape[:-1] + (p, c))
+        with jax.named_scope("fct.collective"):
+            rtext = lax.all_to_all(btext, "w", split_axis=btext.ndim - 2,
+                                   concat_axis=btext.ndim - 2, tiled=True)
+            rkeys = lax.all_to_all(bkeys, "w", split_axis=bkeys.ndim - 2,
+                                   concat_axis=bkeys.ndim - 2, tiled=True)
+            rmask = lax.all_to_all(mask, "w", split_axis=0, concat_axis=0,
+                                   tiled=True)
+        return (rtext.reshape(text.shape[:-1] + (p * c,)),
+                rkeys.reshape(keys.shape[:-1] + (p * c,)),
+                rmask.reshape(p * c))
 
 
 def _route_cn(fact, dims):
@@ -79,7 +91,8 @@ def _route_cn(fact, dims):
     """
     fkeys = fact["keys"]
     if "cols" in fact:
-        fkeys = jnp.take(fkeys, fact["cols"], axis=0)
+        with jax.named_scope("fct.stack"):
+            fkeys = jnp.take(fkeys, fact["cols"], axis=0)
     routed_fact = _route(fact["text"], fkeys, fact["send"])
     routed_dims = [_route(d["text"], d["keys"], d["send"]) for d in dims]
     return routed_fact, routed_dims
@@ -91,27 +104,28 @@ def _mr1_volumes(routed_fact, routed_dims, domains: Tuple[int, ...],
     counting), then fact volume and per-dimension vol contributions
     (Algorithm 3 stage 2).  Returns (vol_fact, dim_vols)."""
     acc = _acc_dtype(accum)
-    ftext, fkeys, fmask = routed_fact
-    m = len(routed_dims)
-    nums = []
-    for (dtext, dkeys, dmask), dom in zip(routed_dims, domains):
-        nums.append(jnp.zeros((dom,), jnp.int32).at[dkeys].add(
-            dmask.astype(jnp.int32), mode="drop"))
-    probes = [nums[i][fkeys[i]].astype(acc) for i in range(m)]
-    fvalid = fmask.astype(acc)
-    vol_fact = fvalid
-    for pr in probes:
-        vol_fact = vol_fact * pr
-    dim_vols = []
-    for i in range(m):
-        others = fvalid
-        for j in range(m):
-            if j != i:
-                others = others * probes[j]
-        contrib = jnp.zeros((domains[i],), acc).at[fkeys[i]].add(
-            others, mode="drop")
-        (dtext, dkeys, dmask) = routed_dims[i]
-        dim_vols.append(contrib[dkeys] * dmask.astype(acc))
+    with jax.named_scope("fct.mr1"):
+        ftext, fkeys, fmask = routed_fact
+        m = len(routed_dims)
+        nums = []
+        for (dtext, dkeys, dmask), dom in zip(routed_dims, domains):
+            nums.append(jnp.zeros((dom,), jnp.int32).at[dkeys].add(
+                dmask.astype(jnp.int32), mode="drop"))
+        probes = [nums[i][fkeys[i]].astype(acc) for i in range(m)]
+        fvalid = fmask.astype(acc)
+        vol_fact = fvalid
+        for pr in probes:
+            vol_fact = vol_fact * pr
+        dim_vols = []
+        for i in range(m):
+            others = fvalid
+            for j in range(m):
+                if j != i:
+                    others = others * probes[j]
+            contrib = jnp.zeros((domains[i],), acc).at[fkeys[i]].add(
+                others, mode="drop")
+            (dtext, dkeys, dmask) = routed_dims[i]
+            dim_vols.append(contrib[dkeys] * dmask.astype(acc))
     return vol_fact, dim_vols
 
 
@@ -130,11 +144,12 @@ def _device_fct_local(fact, dims, *, domains: Tuple[int, ...], vocab: int,
     ftext = routed_fact[0]
 
     # --- MR2: weighted histograms + global aggregation ---
-    hist = weighted_histogram(ftext, vol_fact, vocab,
-                              backend=histogram_backend)
-    for (dtext, dkeys, dmask), w in zip(routed_dims, dim_vols):
-        hist = hist + weighted_histogram(dtext, w.astype(hist.dtype), vocab,
-                                         backend=histogram_backend)
+    with jax.named_scope("fct.mr2"):
+        hist = weighted_histogram(ftext, vol_fact, vocab,
+                                  backend=histogram_backend)
+        for (dtext, dkeys, dmask), w in zip(routed_dims, dim_vols):
+            hist = hist + weighted_histogram(dtext, w.astype(hist.dtype),
+                                             vocab, backend=histogram_backend)
     return hist
 
 
@@ -146,7 +161,8 @@ def _device_fct(fact, dims, *, domains: Tuple[int, ...], vocab: int,
     # where the psum is, instead of inheriting it from upstream
     hist = _device_fct_local(fact, dims, domains=domains, vocab=vocab,
                              histogram_backend=histogram_backend)
-    return lax.psum(hist.astype(_acc_dtype()), "w")
+    with jax.named_scope("fct.collective"):
+        return lax.psum(hist.astype(_acc_dtype()), "w")
 
 
 def _plan_to_arrays(plan: CNPlan):
@@ -205,16 +221,18 @@ def _device_job1(fact, dims, *, domains):
 
 def _device_job2(vol_arrays, *, vocab, histogram_backend):
     """MR2 only: weighted word-count over the vol-arrays + global psum."""
-    hist = weighted_histogram(vol_arrays["fact"]["text"],
-                              vol_arrays["fact"]["vol"], vocab,
-                              backend=histogram_backend)
-    for d in vol_arrays["dims"]:
-        hist = hist + weighted_histogram(d["text"],
-                                         d["vol"].astype(hist.dtype), vocab,
-                                         backend=histogram_backend)
+    with jax.named_scope("fct.mr2"):
+        hist = weighted_histogram(vol_arrays["fact"]["text"],
+                                  vol_arrays["fact"]["vol"], vocab,
+                                  backend=histogram_backend)
+        for d in vol_arrays["dims"]:
+            hist = hist + weighted_histogram(d["text"],
+                                             d["vol"].astype(hist.dtype),
+                                             vocab, backend=histogram_backend)
     # same contract as _device_fct: the collective's accumulator width is
     # pinned at the collective, not inherited from the weight dtype
-    return lax.psum(hist.astype(_acc_dtype()), "w")
+    with jax.named_scope("fct.collective"):
+        return lax.psum(hist.astype(_acc_dtype()), "w")
 
 
 def run_cn_plan_two_jobs(plan: CNPlan, mesh: Mesh,

@@ -23,17 +23,41 @@ Two recording styles:
 Timestamps are ``time.perf_counter_ns`` — monotonic and shared across
 threads of one process, which is what Chrome's trace viewer needs to line
 spans up.
+
+Profiler clock: ``set_annotator(factory)`` installs a context-manager
+factory that every ``span()`` opens around its body under the span's name
+(``repro.runtime`` installs ``jax.profiler.TraceAnnotation``), so the same
+spans appear on the host plane of a ``jax.profiler`` capture.  This module
+stays free of jax: it only calls what it is given.  ``annotate(name)``
+opens the annotator alone, for work whose span is recorded after the fact
+with ``add_span``.
 """
 from __future__ import annotations
 
 import itertools
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from contextlib import contextmanager, nullcontext
+from typing import Any, Callable, ContextManager, Dict, Iterator, List, Optional
 
 _REQUEST_IDS = itertools.count(1)  # itertools.count.__next__ is GIL-atomic
 _TLS = threading.local()
+#: ``name -> context manager`` opened around every span (None: nothing)
+_ANNOTATOR: Optional[Callable[[str], ContextManager]] = None
+
+
+def set_annotator(factory: Optional[Callable[[str], ContextManager]]
+                  ) -> Optional[Callable[[str], ContextManager]]:
+    """Install the profiler annotation factory (None removes it); returns
+    the one it replaces."""
+    global _ANNOTATOR
+    prev, _ANNOTATOR = _ANNOTATOR, factory
+    return prev
+
+
+def annotate(name: str) -> ContextManager:
+    """The installed annotator's context for ``name``, or a no-op."""
+    return _ANNOTATOR(name) if _ANNOTATOR is not None else nullcontext()
 
 
 class Span:
@@ -147,23 +171,25 @@ def current_trace() -> Optional[Trace]:
 
 @contextmanager
 def span(name: str, **args) -> Iterator[Span]:
-    """Open a nested span on the thread-active trace; no-op (but still
-    yields a scratch ``Span`` whose ``args`` may be set) when none is
+    """Open a nested span on the thread-active trace, inside a profiler
+    annotation of the same name; no-op (but still yields a scratch ``Span``
+    whose ``args`` may be set, and opens no annotation) when none is
     active, so instrumentation sites need no guards."""
     state = getattr(_TLS, "state", None)
     if state is None:
         yield Span(name, 0, 0, 0, 0, threading.get_ident(), dict(args))
         return
     trace, stack = state
-    sp = Span(name, next(trace._seq), stack[-1], time.perf_counter_ns(), 0,
-              threading.get_ident(), dict(args))
-    stack.append(sp.span_id)
-    try:
-        yield sp
-    finally:
-        sp.dur_ns = time.perf_counter_ns() - sp.t0_ns
-        stack.pop()
-        trace._record(sp)
+    with annotate(name):
+        sp = Span(name, next(trace._seq), stack[-1], time.perf_counter_ns(),
+                  0, threading.get_ident(), dict(args))
+        stack.append(sp.span_id)
+        try:
+            yield sp
+        finally:
+            sp.dur_ns = time.perf_counter_ns() - sp.t0_ns
+            stack.pop()
+            trace._record(sp)
 
 
 @contextmanager

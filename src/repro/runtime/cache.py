@@ -15,6 +15,9 @@ least-recently-used executable is dropped once the cap is exceeded.
 Dropping the jit wrapper releases its compiled executable; a later request
 for that signature simply recompiles (a miss + trace, counted as usual).
 
+Each jitted program is named after its key's kind (``program_name``), so
+profiles tell ``fct_store`` from ``fct_topk`` by module name.
+
 ``LruDict`` is the shared bounded-LRU primitive — the session-level caches
 in ``repro/api`` (tuple sets, routing plans) reuse it rather than re-rolling
 the eviction bookkeeping.
@@ -62,6 +65,13 @@ class LruDict(OrderedDict):
             # fct-lint: waive[R3] -- externally-locked primitive (docstring): every caller holds its own lock around put/hit
             self.evictions += 1
         return value
+
+
+def program_name(key: Hashable) -> str:
+    """The jitted program's name: the key's kind (its first element, e.g.
+    ``fct_store``), so a profile's XLA module reads ``jit_fct_store(...)``
+    and its host line ``PjitFunction(fct_store)``."""
+    return str(key[0] if isinstance(key, tuple) else key)
 
 
 class ExecutableCache:
@@ -120,12 +130,13 @@ class ExecutableCache:
         self._c_misses.inc()
         inner = builder()
 
-        def traced(*args: Any):
+        def program(*args: Any):
             self._c_traces.inc()  # runs only under tracing, not per call
             return inner(*args)
 
+        program.__name__ = program.__qualname__ = program_name(key)
         with self._lock:
-            return self._fns.put(key, jax.jit(traced))
+            return self._fns.put(key, jax.jit(program))
 
     def __contains__(self, key: Hashable) -> bool:
         return key in self._fns

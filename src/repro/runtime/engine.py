@@ -116,19 +116,26 @@ def _vmapped_cns(fact, dims, sig: PlanSignature, histogram_backend: str,
     hists = jax.vmap(one_cn)(fact, dims)            # [N, vocab]
     acc = sig.accum.dtype
     pad = vocab_padded(sig.vocab, sig.n_devices) - sig.vocab
-    if reduce_cns:
-        total = jnp.sum(hists, axis=0, dtype=acc)
+    with jax.named_scope("fct.reduce"):
+        if reduce_cns:
+            total = jnp.sum(hists, axis=0, dtype=acc)
+            if not reduce_scatter:
+                with jax.named_scope("fct.collective"):
+                    return lax.psum(total, "w")
+            if pad:
+                total = jnp.pad(total, (0, pad))
+            with jax.named_scope("fct.collective"):
+                return lax.psum_scatter(total, "w", scatter_dimension=0,
+                                        tiled=True)
+        hists = hists.astype(acc)                   # per-CN, one collective
         if not reduce_scatter:
-            return lax.psum(total, "w")
+            with jax.named_scope("fct.collective"):
+                return lax.psum(hists, "w")
         if pad:
-            total = jnp.pad(total, (0, pad))
-        return lax.psum_scatter(total, "w", scatter_dimension=0, tiled=True)
-    hists = hists.astype(acc)                       # per-CN, one collective
-    if not reduce_scatter:
-        return lax.psum(hists, "w")
-    if pad:
-        hists = jnp.pad(hists, ((0, 0), (0, pad)))
-    return lax.psum_scatter(hists, "w", scatter_dimension=1, tiled=True)
+            hists = jnp.pad(hists, ((0, 0), (0, pad)))
+        with jax.named_scope("fct.collective"):
+            return lax.psum_scatter(hists, "w", scatter_dimension=1,
+                                    tiled=True)
 
 
 def _out_spec(reduce_cns: bool, reduce_scatter: bool):
@@ -157,11 +164,12 @@ def _build_batched_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
     spec = {"text": shard, "keys": shard, "send": shard}
 
     def device_fn(fact, dims):
-        fact = {k: jnp.squeeze(v, 1) for k, v in fact.items()}
-        dims = [{k: jnp.squeeze(v, 1) for k, v in d.items()} for d in dims]
-        with jax.named_scope("fct.group_batched"):
-            return _vmapped_cns(fact, dims, sig, histogram_backend,
-                                reduce_cns, reduce_scatter)
+        with jax.named_scope("fct.stack"):
+            fact = {k: jnp.squeeze(v, 1) for k, v in fact.items()}
+            dims = [{k: jnp.squeeze(v, 1) for k, v in d.items()}
+                    for d in dims]
+        return _vmapped_cns(fact, dims, sig, histogram_backend, reduce_cns,
+                            reduce_scatter)
 
     return shard_map(device_fn, mesh=mesh, in_specs=(spec, [spec] * sig.m),
                      out_specs=_out_spec(reduce_cns, reduce_scatter),
@@ -199,10 +207,10 @@ def _build_store_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
                 out["cols"] = rel["cols"]
             return out
 
-        with jax.named_scope("fct.group_store"):
-            return _vmapped_cns(stack(fact), [stack(d) for d in dims], sig,
-                                histogram_backend, reduce_cns,
-                                reduce_scatter)
+        with jax.named_scope("fct.stack"):
+            fact, dims = stack(fact), [stack(d) for d in dims]
+        return _vmapped_cns(fact, dims, sig, histogram_backend, reduce_cns,
+                            reduce_scatter)
 
     return shard_map(device_fn, mesh=mesh,
                      in_specs=(fact_spec, [rel_spec] * sig.m),
@@ -291,14 +299,19 @@ def _build_topk_fn(sig: PlanSignature, mesh: Mesh, reduce_scatter: bool,
         cand = ids[local]
         if not reduce_scatter:
             return v[:k_eff], cand[:k_eff], wrapped
-        av = lax.all_gather(v, "w", tiled=True)        # [P * shard_k]
-        ai = lax.all_gather(cand, "w", tiled=True)
-        aw = lax.all_gather(wrapped[None], "w", tiled=True)
+        with jax.named_scope("fct.collective"):
+            av = lax.all_gather(v, "w", tiled=True)    # [P * shard_k]
+            ai = lax.all_gather(cand, "w", tiled=True)
+            aw = lax.all_gather(wrapped[None], "w", tiled=True)
         fv, pos = lax.top_k(av, k_eff)
         return fv, ai[pos], jnp.max(aw)
 
+    def scoped_fn(hist, kw, excl):
+        with jax.named_scope("fct.topk"):
+            return device_fn(hist, kw, excl)
+
     hist_spec = P("w") if reduce_scatter else P()
-    return shard_map(device_fn, mesh=mesh,
+    return shard_map(scoped_fn, mesh=mesh,
                      in_specs=(hist_spec, P(), hist_spec),
                      out_specs=(P(), P(), P()), check_vma=False)
 
@@ -394,19 +407,15 @@ class FCTEngine:
     def _dispatch(self, sig: PlanSignature, group: Sequence[CNPlan],
                   mesh: Mesh, histogram_backend: str, reduce_cns: bool,
                   store=None):
-        """Span/profiler shell around :meth:`_dispatch_group`: one
-        ``engine.dispatch_group`` span per launch on the active trace, and a
-        ``jax.profiler.TraceAnnotation`` so device profiles line host spans
-        up with XLA activity."""
+        """Span shell around :meth:`_dispatch_group`: one
+        ``engine.dispatch_group`` span per launch on the active trace (and,
+        through the span annotator, on the profiler's host plane)."""
         path = "store" if store is not None else "host"
         family = "sum" if reduce_cns else "percn"
         with obs_span("engine.dispatch_group", n_cns=len(group), path=path,
                       family=family, n_devices=sig.n_devices):
-            with jax.profiler.TraceAnnotation(
-                    f"fct.dispatch_group:{path}.{family}"):
-                return self._dispatch_group(sig, group, mesh,
-                                            histogram_backend, reduce_cns,
-                                            store)
+            return self._dispatch_group(sig, group, mesh, histogram_backend,
+                                        reduce_cns, store)
 
     def _dispatch_group(self, sig: PlanSignature, group: Sequence[CNPlan],
                         mesh: Mesh, histogram_backend: str, reduce_cns: bool,
@@ -428,6 +437,12 @@ class FCTEngine:
         ZERO column bytes.  Without one, the legacy host pad/stack path is
         used (the pre-store engine — kept as the equivalence baseline and
         for storeless callers).
+
+        Two child spans split the host work: ``store.send_tables`` (the
+        numpy pad-and-stack of send tables and key-column indices; the host
+        path's whole ``stack_group``) and ``engine.enqueue`` (the jitted
+        call: argument conversion and the host-to-device transfer it
+        starts).
         """
         n_stack = len(group)
         if not reduce_cns and self.bucket:
@@ -451,9 +466,10 @@ class FCTEngine:
                                              reduce_scatter=rs))
             self._c_bytes.inc(shipped)
         else:
-            fact, dims = stack_group(group, sig)
-            if n_stack > len(group):
-                fact, dims = pad_cn_axis(fact, dims, n_stack)
+            with obs_span("store.send_tables", n_stack=n_stack):
+                fact, dims = stack_group(group, sig)
+                if n_stack > len(group):
+                    fact, dims = pad_cn_axis(fact, dims, n_stack)
             kind = "fct_batched" if reduce_cns else "fct_batched_percn"
             key = (kind, sig, n_stack, histogram_backend, mesh, x64, agg)
             fn = self.cache.get_or_build(
@@ -466,7 +482,8 @@ class FCTEngine:
                 d["send"].nbytes for d in dims)
             self._c_bytes.inc(shipped)
             self._c_column_bytes.inc(columns)
-        out = fn(fact, dims)
+        with obs_span("engine.enqueue", kind=kind):
+            out = fn(fact, dims)
         self._c_batches.inc()
         self._c_cns.inc(len(group))
         return out

@@ -30,7 +30,7 @@ columns.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -277,31 +277,31 @@ def store_group_args(store: RelationStore, plans: Sequence[CNPlan],
     send tables plus the fact's key-column indices (``shipped_bytes``
     counts exactly that).  Slots past ``len(plans)`` are null plans: they
     alias the first plan's store-resident columns and route nothing (all
-    ``-1`` send), contributing exactly zero to every histogram.
+    ``-1`` send), contributing exactly zero to every histogram.  The host
+    pad-and-stack runs under the ``store.send_tables`` span.
     """
     pad = n_stack - len(plans)
-
-    def one_relation(refs_sends: List[Tuple[RelationRef, np.ndarray]],
-                     rsig: RelationSig) -> Dict:
-        cols = [store.columns(ref, rsig.rows, rsig.text_len)
-                for ref, _ in refs_sends]
-        sends = [_pad_send(send, rsig.cap) for _, send in refs_sends]
+    rsigs = (sig.fact,) + tuple(sig.dims)
+    routes = [[p.fact for p in plans]] + [
+        [p.dims[p.included[j]] for p in plans] for j in range(len(sig.dims))]
+    # store hits (an upload on a miss) before the send tables are stacked
+    cols = [[store.columns(r.ref, rsig.rows, rsig.text_len) for r in rel]
+            for rel, rsig in zip(routes, rsigs)]
+    with obs_span("store.send_tables", n_stack=n_stack):
+        rels = []
+        for rel, rsig, cs in zip(routes, rsigs, cols):
+            sends = [_pad_send(r.send, rsig.cap) for r in rel]
+            if pad:
+                cs = cs + [cs[0]] * pad
+                sends.extend([_null_send(sends[0].shape[0], rsig.cap)] * pad)
+            rels.append({"text": [c.text for c in cs],
+                         "keys": [c.keys for c in cs],
+                         "send": np.stack(sends)})
+        fact, dims = rels[0], rels[1:]
+        key_cols = [np.asarray(p.fact.key_cols, np.int32) for p in plans]
         if pad:
-            cols.extend([cols[0]] * pad)
-            P_dev = sends[0].shape[0]
-            sends.extend([_null_send(P_dev, rsig.cap)] * pad)
-        return {"text": [c.text for c in cols],
-                "keys": [c.keys for c in cols],
-                "send": np.stack(sends)}
-
-    fact = one_relation([(p.fact.ref, p.fact.send) for p in plans], sig.fact)
-    key_cols = [np.asarray(p.fact.key_cols, np.int32) for p in plans]
-    if pad:
-        key_cols.extend([key_cols[0]] * pad)
-    fact["cols"] = np.stack(key_cols)
-    dims = [one_relation([(p.dims[p.included[j]].ref,
-                           p.dims[p.included[j]].send) for p in plans], rsig)
-            for j, rsig in enumerate(sig.dims)]
+            key_cols.extend([key_cols[0]] * pad)
+        fact["cols"] = np.stack(key_cols)
     shipped = fact["send"].nbytes + fact["cols"].nbytes + sum(
         d["send"].nbytes for d in dims)
     return (fact, dims), shipped

@@ -12,9 +12,11 @@ from repro.obs import (
     JsonLinesReporter,
     MetricsRegistry,
     Trace,
+    annotate,
     chrome_trace,
     current_trace,
     render_key,
+    set_annotator,
     span,
     write_chrome_trace,
 )
@@ -184,6 +186,66 @@ def test_span_without_active_trace_is_noop():
     assert current_trace() is None
 
 
+class _Recorder:
+    """Span annotator that records the names it opens and closes."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name):
+        rec = self
+
+        class _Ctx:
+            def __enter__(self):
+                rec.events.append(("open", name))
+
+            def __exit__(self, *exc):
+                rec.events.append(("close", name))
+
+        return _Ctx()
+
+    def opened(self):
+        return [n for kind, n in self.events if kind == "open"]
+
+
+@pytest.fixture
+def recorder():
+    rec = _Recorder()
+    prev = set_annotator(rec)
+    yield rec
+    set_annotator(prev)
+
+
+def test_runtime_installs_the_profiler_annotator():
+    import jax
+
+    import repro.runtime  # noqa: F401  (installs on import)
+    prev = set_annotator(None)
+    set_annotator(prev)
+    assert prev is jax.profiler.TraceAnnotation
+
+
+def test_span_opens_the_annotator_under_its_name(recorder):
+    tr = Trace()
+    with tr.activate():
+        with span("engine.dispatch_group"):
+            with span("engine.enqueue"):
+                pass
+    assert recorder.events == [
+        ("open", "engine.dispatch_group"), ("open", "engine.enqueue"),
+        ("close", "engine.enqueue"), ("close", "engine.dispatch_group")]
+    with annotate("dispatch"):
+        pass
+    assert recorder.opened()[-1] == "dispatch"
+
+
+def test_span_without_active_trace_calls_no_annotator(recorder):
+    with span("orphan"):
+        pass
+    assert current_trace() is None
+    assert recorder.events == []
+
+
 def test_add_span_records_from_foreign_threads():
     tr = Trace()
     results = []
@@ -265,6 +327,29 @@ def test_sync_and_pipelined_paths_share_span_and_timing_shape():
         # distinct request ids per submission
     ids = {f.result().trace.request_id for f in futs}
     assert len(ids) == 3
+    session.close()
+
+
+def test_dispatch_spans_reach_the_profiler_once(recorder):
+    """The request's program spans are all opened on the annotator, and the
+    dispatch's host work splits into send-table stacking and the enqueue."""
+    schema, kws = _crafted_schema(seed=0)
+    session = FCTSession(schema, metrics=MetricsRegistry())
+    resp = session.query(FCTRequest(keywords=tuple(kws), r_max=3))
+    opened = recorder.opened()
+    for name in ("plan", "dispatch", "engine.dispatch_group",
+                 "store.send_tables", "engine.enqueue", "collect"):
+        assert name in opened, (name, opened)
+    assert opened.count("dispatch") == opened.count("collect") == 1
+    spans = resp.trace.spans()
+    groups = {s.span_id: s for s in spans
+              if s.name == "engine.dispatch_group"}
+    children = [s for s in spans
+                if s.name in ("store.send_tables", "engine.enqueue")]
+    assert groups and len(children) == 2 * len(groups)
+    for s in children:
+        g = groups[s.parent_id]
+        assert g.t0_ns <= s.t0_ns and s.t0_ns + s.dur_ns <= g.t0_ns + g.dur_ns
     session.close()
 
 

@@ -189,3 +189,100 @@ def test_fct_store_program_shards_over_v5e_2x2(topo):
 
 def test_fct_store_star_program_shards_over_v5e_2x2(topo):
     _check_sharded_store_program(topo, SF1_STAR_SIG_4CHIP)
+
+
+_STAGE = re.compile(r"\bfct\.(stack|route|mr1|mr2|reduce|topk|collective)\b")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+
+
+def _entry_op_stages(text: str) -> dict:
+    """Per instruction of a compiled module's entry computation: its opcode
+    (a fusion's kind), the innermost ``fct.*`` stage of its own
+    ``op_name`` (None without one), and the stages named inside the
+    computations it calls, transitively."""
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(", line)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+
+    def inner(comp, seen):
+        out = set()
+        for line in comps.get(comp, []):
+            out.update(_STAGE.findall(line))
+            for callee in _CALLED.findall(line):
+                if callee not in seen:
+                    seen.add(callee)
+                    out |= inner(callee, seen)
+        return out
+
+    ops = {}
+    for line in comps[entry]:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*?\s([\w-]+)\(", line)
+        if not m:
+            continue
+        name_ = re.search(r'op_name="([^"]*)"', line)
+        own = _STAGE.findall(name_.group(1)) if name_ else []
+        kind = re.search(r"kind=(k\w+)", line)
+        ops[m.group(1)] = {
+            "opcode": kind.group(1) if kind else m.group(2),
+            "own": own[-1] if own else None,
+            "inner": set().union(*[inner(c, {c}) for c in
+                                   _CALLED.findall(line)] or [set()]),
+            "operands": re.findall(r"%([\w.\-]+)", line.split("=", 1)[1])}
+    return ops
+
+
+def _downstream_stages(ops: dict, name: str) -> set:
+    """The stages of the nearest ops fed by ``name`` that name one, looking
+    through those that do not (tuple elements, bitcasts, copies)."""
+    out, todo, seen = set(), [name], {name}
+    while todo:
+        src = todo.pop()
+        for user, op in ops.items():
+            if src in op["operands"] and user not in seen:
+                seen.add(user)
+                stages = {op["own"]} if op["own"] else op["inner"]
+                if stages:
+                    out |= stages
+                else:
+                    todo.append(user)
+    return out
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_unnamed_scatter_ops_name_mr1_inside(topo, n_dev):
+    """The TPU compiler turns the MR1 scatter-adds into custom fusions that
+    carry no ``op_name`` of their own, so a trace leaves them unstaged.
+    Their fused computations still name the stage: every such op of the
+    per-CN program is MR1's.  At the cell's size (2^25 routed rows) the
+    compiler also puts a sort, likewise unnamed, before the largest; these
+    shapes make none, and one that appears must feed MR1."""
+    fact = 2**16
+    sig = PlanSignature(
+        n_devices=n_dev, vocab=256,
+        fact=RelationSig(rows=fact // n_dev, cap=fact // n_dev // n_dev,
+                         text_len=14, key_width=3),
+        dims=(RelationSig(rows=512, cap=512, text_len=26, domain=2**15),
+              RelationSig(rows=64, cap=64, text_len=7, domain=2**12),
+              RelationSig(rows=32, cap=32, text_len=33, domain=2**10)),
+        accum=INT32_CHECKED)
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("w",))
+    fn = _build_store_fn(sig, mesh, "pallas", 4, reduce_cns=False,
+                         reduce_scatter=n_dev > 1)
+    text = jax.jit(fn).lower(*_store_args(sig, mesh, 4)).compile().as_text()
+    ops = _entry_op_stages(text)
+    custom = {n: op for n, op in ops.items()
+              if op["opcode"] == "kCustom" and op["own"] is None}
+    assert custom
+    for name, op in custom.items():
+        assert op["inner"] == {"mr1"}, (name, op["inner"])
+    for name, op in ops.items():
+        if op["opcode"] == "sort" and op["own"] is None:
+            assert _downstream_stages(ops, name) == {"mr1"}, name
