@@ -12,9 +12,10 @@ abstract ``ShapeDtypeStruct`` arguments for representative
 C1 (collective census)
     Exactly ONE cross-device reduction collective per dispatch: a
     vocab-sharded ``reduce_scatter`` on multi-device meshes, a ``psum`` at
-    P=1.  The routing stage contributes exactly ``3 * (1 + m)``
-    ``all_to_all``\\ s (text/keys/mask per relation) and nothing else moves
-    data across devices.  A second reduction collective means someone
+    P=1.  On multi-device meshes the routing stage contributes exactly
+    ``3 * (1 + m)`` ``all_to_all``\\ s (text/keys/mask per relation) and
+    nothing else moves data across devices; at P=1 it routes in place and
+    contributes none.  A second reduction collective means someone
     re-aggregated an already-aggregated histogram — double traffic and,
     under psum_scatter, wrong totals.
 
@@ -261,12 +262,12 @@ def check_contract(kind: str, sig: PlanSignature, n_stack: int, mesh,
         failures.append(
             f"{tag} C1: aggregation uses {got}, expected {expected} "
             f"at P={sig.n_devices}")
-    n_a2a = 3 * (1 + sig.m)
+    n_a2a = 3 * (1 + sig.m) if sig.n_devices > 1 else 0
     if counts["all_to_all"] != n_a2a:
         failures.append(
             f"{tag} C1: {counts['all_to_all']} all_to_alls, expected "
-            f"{n_a2a} (text/keys/mask per relation) — the routing stage "
-            f"grew extra shuffles")
+            f"{n_a2a} (text/keys/mask per relation, none at P=1) — the "
+            f"routing stage grew extra shuffles")
     extras = {n: c for n, c in counts.items()
               if c and n not in REDUCTION_PRIMITIVES + ("all_to_all",)}
     if extras:

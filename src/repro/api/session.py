@@ -54,7 +54,8 @@ _ENGINE_COUNTERS = ("hits", "misses", "traces", "evictions",
                     "batches_run", "cns_run", "bytes_shipped",
                     "column_bytes_shipped", "store_uploads", "store_hits",
                     "store_upload_bytes", "store_chunk_assembles",
-                    "device_to_host_bytes", "groups_pruned", "pruned_rows")
+                    "device_to_host_bytes", "groups_pruned", "pruned_rows",
+                    "routes_in_place")
 
 
 def _cn_includes(cn: StarCN, role: str, dim_index: int) -> bool:

@@ -1,8 +1,9 @@
 """The two MapReduce jobs as one fused shard_map program (paper §4.3–§4.4).
 
 MR¹ (statistics): route tuple-set rows per the static plan (gather →
-``all_to_all`` → mask), build dense ``num``-arrays per dimension, probe them
-per fact row to produce fact volumes and per-dimension ``vol`` contributions.
+``all_to_all`` → mask; in place on a one-device mesh), build dense
+``num``-arrays per dimension, probe them per fact row to produce fact
+volumes and per-dimension ``vol`` contributions.
 
 MR² (term frequency): weighted token histogram of every routed payload with
 its volume (Pallas ``fct_count`` on TPU, segment-sum ref elsewhere), then one
@@ -16,9 +17,10 @@ path is the default: on a TPU there is no reason to spill the intermediate.
 Each stage of the device body runs under a ``jax.named_scope``, so every op's
 HLO ``op_name`` (and a profile's op metadata) names its stage: ``fct.stack``
 (CN slots and the fact's key-column select), ``fct.route`` (send-table
-gathers, masks), ``fct.mr1`` (num-arrays, probes, volumes), ``fct.mr2``
-(histogram inputs and the ``fct_count`` calls), ``fct.reduce`` (cross-CN
-sum, accumulator casts, vocab pads), ``fct.topk`` (the finalize program) and
+gathers and masks; on one device the masks and row cuts alone),
+``fct.mr1`` (num-arrays, probes, volumes), ``fct.mr2`` (histogram inputs
+and the ``fct_count`` calls), ``fct.reduce`` (cross-CN sum, accumulator
+casts, vocab pads), ``fct.topk`` (the finalize program) and
 ``fct.collective`` (every cross-device collective, scoped at its call site
 so it is the innermost scope of the op).
 """
@@ -35,7 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.accum import AccumPolicy
 from repro.core.plan import CNPlan, lane_major
-from repro.data.schema import StarSchema
+from repro.data.schema import PAD_ID, StarSchema
 from repro.kernels.fct_count.ops import weighted_histogram
 
 
@@ -54,6 +56,15 @@ def _acc_dtype(accum: Optional[AccumPolicy] = None):
     return (accum or AccumPolicy.current()).dtype
 
 
+def _fit_rows(col, c: int, fill: int):
+    """``col`` cut or padded along its row (last) axis to ``c`` rows."""
+    s = col.shape[-1]
+    if c <= s:
+        return col[..., :c]
+    return jnp.pad(col, ((0, 0),) * (col.ndim - 1) + ((0, c - s),),
+                   constant_values=fill)
+
+
 def _route(text, keys, send):
     """Gather rows into per-destination buffers and all_to_all them.
 
@@ -61,9 +72,19 @@ def _route(text, keys, send):
     ``repro.core.plan.lane_major``): text [L, S]; keys [S] or [m, S];
     send [P, C] (local row idx, -1 pad).  Returns (text [L, P*C],
     keys [P*C] / [m, P*C], mask [P*C]) of received rows.
+
+    With one destination (``P == 1``, a static shape) the shuffle is the
+    identity: the planner keeps every row at its own slot (``send[0, c]``
+    is ``c`` or -1, ``core.plan._send_table``), so the routed columns are
+    the relation's own, cut or padded to ``C`` rows, and nothing is
+    gathered or exchanged.  Slots the mask drops carry weight 0 downstream,
+    whatever their contents.
     """
     p, c = send.shape
     with jax.named_scope("fct.route"):
+        if p == 1:
+            return (_fit_rows(text, c, PAD_ID), _fit_rows(keys, c, 0),
+                    (send >= 0).reshape(c))
         idx = jnp.maximum(send, 0).reshape(-1)
         mask = send >= 0
         btext = jnp.take(text, idx, axis=-1).reshape(text.shape[:-1] + (p, c))

@@ -251,7 +251,21 @@ class CNPlan:
 
 def _send_table(pairs_src: np.ndarray, pairs_dst: np.ndarray,
                 pairs_local: np.ndarray, P: int) -> Tuple[np.ndarray, int]:
-    """Build [P, P, C] send table from (src, dst, local_idx) triples."""
+    """Build [P, P, C] send table from (src, dst, local_idx) triples.
+
+    On one device every row keeps its own slot: ``table[0, 0, c]`` is ``c``
+    or -1 (a row no live task needs), and ``C`` is the last sent index + 1,
+    so the device routes in place (``core.fct._route``).
+    """
+    if P == 1:
+        C = int(pairs_local.max()) + 1 if len(pairs_local) else 1
+        table = np.full((1, 1, C), -1, np.int32)
+        table[0, 0, pairs_local] = pairs_local
+        # the in-place route reads row c at slot c: a row sent twice would
+        # share a slot and drop one copy
+        assert np.count_nonzero(table >= 0) == len(pairs_local), \
+            "one-device send table sends a row twice"
+        return table, int(len(pairs_local))
     counts = np.zeros((P, P), np.int64)
     np.add.at(counts, (pairs_src, pairs_dst), 1)
     C = max(1, int(counts.max()))

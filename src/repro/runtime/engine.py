@@ -367,6 +367,9 @@ class FCTEngine:
         # tenants, sync callers); the registry lock guards the counters
         self._c_batches = self.metrics.counter("engine.batches_run")
         self._c_cns = self.metrics.counter("engine.cns_run")
+        # relations (fact and dims, per CN slot) whose route is the identity
+        # on a one-device mesh (core.fct._route): no gather, no all_to_all
+        self._c_in_place = self.metrics.counter("engine.routes_in_place")
         self._c_bytes = self.metrics.counter("engine.bytes_shipped")
         self._c_column_bytes = self.metrics.counter(
             "engine.column_bytes_shipped")
@@ -486,6 +489,8 @@ class FCTEngine:
             out = fn(fact, dims)
         self._c_batches.inc()
         self._c_cns.inc(len(group))
+        if sig.n_devices == 1:
+            self._c_in_place.inc(n_stack * (1 + sig.m))
         return out
 
     def _collect(self, lazy) -> np.ndarray:
@@ -724,14 +729,15 @@ class FCTEngine:
 
     def stats(self) -> dict:
         out = self.cache.stats()
-        (batches, cns, shipped, columns, d2h, g_pruned,
-         rows_pruned) = self.metrics.values(
+        (batches, cns, shipped, columns, d2h, g_pruned, rows_pruned,
+         in_place) = self.metrics.values(
             self._c_batches, self._c_cns, self._c_bytes,
             self._c_column_bytes, self._c_d2h, self._c_groups_pruned,
-            self._c_pruned_rows)
+            self._c_pruned_rows, self._c_in_place)
         out.update(batches_run=batches, cns_run=cns, bytes_shipped=shipped,
                    column_bytes_shipped=columns, device_to_host_bytes=d2h,
-                   groups_pruned=g_pruned, pruned_rows=rows_pruned)
+                   groups_pruned=g_pruned, pruned_rows=rows_pruned,
+                   routes_in_place=in_place)
         return out
 
 

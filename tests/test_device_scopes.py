@@ -103,8 +103,16 @@ def _check(result: dict, n_devices: int) -> None:
         stages = {_innermost(n) for _, n in got["ops"]}
         if kind == "fct_topk":
             assert stages <= {"topk", "collective"}, stages
-        else:
+        elif n_devices > 1:
             assert {"route", "mr1", "mr2"} <= stages, (kind, stages)
+        else:
+            # one device routes in place: nothing is gathered or exchanged
+            # under fct.route
+            assert {"mr1", "mr2"} <= stages, (kind, stages)
+            routed = [(op, n) for op, n in got["ops"]
+                      if op in ("gather", "all-to-all")
+                      and _innermost(n) == "route"]
+            assert not routed, (kind, routed)
         if n_devices > 1:
             opcodes = {op for op, _ in got["ops"]}
             want = {"all-gather"} if kind == "fct_topk" else {"all-to-all"}

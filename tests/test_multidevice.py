@@ -62,6 +62,8 @@ SCRIPT = textwrap.dedent("""
                     resp.all_freqs).tobytes()).hexdigest()
         out[f"rs={rs}/accum"] = single[0].accum_policy
         out[f"rs={rs}/row_imbalance"] = single[1].row_imbalance
+        out[f"routes_in_place_rs{int(rs)}"] = \
+            session.stats()["routes_in_place"]
     print("RESULT" + json.dumps(out))
 """)
 
@@ -110,6 +112,15 @@ def test_reduce_scatter_matches_psum(results, x64):
 def test_accum_policy_reported(results):
     assert results[(8, False)]["rs=True/accum"] == "int32-checked"
     assert results[(8, True)]["rs=True/accum"] == "int64-exact"
+
+
+@pytest.mark.parametrize("x64", [False, True],
+                         ids=["int32-checked", "int64-exact"])
+def test_routes_in_place_only_on_one_device(results, x64):
+    # one device routes every relation in place; eight gather and exchange
+    for rs in (0, 1):
+        assert results[(1, x64)][f"routes_in_place_rs{rs}"] > 0
+        assert results[(8, x64)][f"routes_in_place_rs{rs}"] == 0
 
 
 def test_adaptive_reduces_row_imbalance_on_8(results):
