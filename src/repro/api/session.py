@@ -39,10 +39,9 @@ import numpy as np
 from repro.api.pipeline import QueryPipeline
 from repro.api.request import AppendResult, FCTRequest, FCTResponse
 from repro.core.accum import AccumPolicy
-from repro.core.candidate_network import (StarCN, TupleSets,
-                                          enumerate_star_cns, prune_empty_cns)
+from repro.core.candidate_network import StarCN, TupleSets, prune_empty_cns
 from repro.core.plan import CNPlan, build_cn_plan
-from repro.core.star import topk_terms
+from repro.core.star import schema_cns, topk_terms
 from repro.data.schema import (PAD_ID, StarSchema, keyword_mask,
                                tokens_histogram)
 from repro.obs import Trace, annotate, default_registry, maybe_activate
@@ -55,16 +54,18 @@ _ENGINE_COUNTERS = ("hits", "misses", "traces", "evictions",
                     "column_bytes_shipped", "store_uploads", "store_hits",
                     "store_upload_bytes", "store_chunk_assembles",
                     "device_to_host_bytes", "groups_pruned", "pruned_rows",
-                    "routes_in_place")
+                    "routes_in_place", "hop_rows")
 
 
 def _cn_includes(cn: StarCN, role: str, dim_index: int) -> bool:
     """Does the CN's join tree contain the mutated relation?  A CN that
     doesn't is untouched by an append — its delta is exactly zero, so the
     delta dispatch skips it (running it would wrongly re-count its FULL
-    histogram, since its tuple sets carry no append boundary)."""
+    histogram, since its tuple sets carry no append boundary).  A relation
+    appears at most once in a tree CN, so the CN's delta is the CN over
+    the relation's new rows alone."""
     if role == "fact":
-        return cn.single_dim < 0
+        return cn.has_fact
     return cn.single_dim == dim_index or (
         cn.single_dim < 0 and cn.dim_masks[dim_index] is not None)
 
@@ -329,7 +330,7 @@ class FCTSession:
         with self._plan_lock:
             cns = self._cn_lists.get(key)
         if cns is None:
-            cns = enumerate_star_cns(n_keywords, self.schema.m, r_max)
+            cns = schema_cns(self.schema, n_keywords, r_max)
             with self._plan_lock:
                 cns = self._cn_lists.setdefault(key, cns)
         return cns

@@ -18,7 +18,9 @@ Each stage of the device body runs under a ``jax.named_scope``, so every op's
 HLO ``op_name`` (and a profile's op metadata) names its stage: ``fct.stack``
 (CN slots and the fact's key-column select), ``fct.route`` (send-table
 gathers and masks; on one device the masks and row cuts alone),
-``fct.mr1`` (num-arrays, probes, volumes), ``fct.mr2`` (histogram inputs
+``fct.mr1`` (num-arrays, probes, volumes; inside it ``fct.hop``, a
+sub-dimension's num-array and volumes passed through its parent),
+``fct.mr2`` (histogram inputs
 and the ``fct_count`` calls), ``fct.reduce`` (cross-CN sum, accumulator
 casts, vocab pads), ``fct.topk`` (the finalize program) and
 ``fct.collective`` (every cross-device collective, scoped at its call site
@@ -120,48 +122,100 @@ def _route_cn(fact, dims):
 
 
 def _mr1_volumes(routed_fact, routed_dims, domains: Tuple[int, ...],
-                 accum: Optional[AccumPolicy] = None):
+                 accum: Optional[AccumPolicy] = None,
+                 links: Tuple[Tuple[int, int], ...] = ()):
     """MR¹ statistics on routed relations: num-arrays (combine + reduce-side
     counting), then fact volume and per-dimension vol contributions
-    (Algorithm 3 stage 2).  Returns (vol_fact, dim_vols)."""
+    (Algorithm 3 stage 2).  Returns (vol_fact, dim_vols).
+
+    ``links[i]`` is ``(parent slot, parent key row)`` of a hop
+    sub-dimension (``(-1, 0)`` for a dimension of the root; empty for a
+    star): under ``fct.hop`` the sub-dimension's num-array is gathered at
+    its parent's routed FK and multiplies the parent's rows into the
+    parent's num-array (upward), and the parent rows' volumes without it
+    are scatter-added into its key domain and gathered at its rows
+    (downward).  The root's dimensions come first in ``routed_dims``, in
+    the order of the fact's key columns."""
     acc = _acc_dtype(accum)
+    parent = [p for p, _ in links] or [-1] * len(routed_dims)
+    top = [i for i, p in enumerate(parent) if p < 0]
     with jax.named_scope("fct.mr1"):
         ftext, fkeys, fmask = routed_fact
-        m = len(routed_dims)
+        m = len(top)
+        # upward: each parent row's count of sub-dimension rows it joins
+        ups = {}                    # parent slot -> [(sub slot, gathered)]
+        for c, (p, row) in enumerate(links):
+            if p < 0:
+                continue
+            with jax.named_scope("fct.hop"):
+                (ctext, ckeys, cmask) = routed_dims[c]
+                cnum = jnp.zeros((domains[c],), acc).at[ckeys].add(
+                    cmask.astype(acc), mode="drop")
+                ups.setdefault(p, []).append(
+                    (c, cnum[routed_dims[p][1][row]]))
         nums = []
-        for (dtext, dkeys, dmask), dom in zip(routed_dims, domains):
-            nums.append(jnp.zeros((dom,), jnp.int32).at[dkeys].add(
-                dmask.astype(jnp.int32), mode="drop"))
+        for i in top:
+            (dtext, dkeys, dmask), dom = routed_dims[i], domains[i]
+            if i not in ups:
+                nums.append(jnp.zeros((dom,), jnp.int32).at[dkeys].add(
+                    dmask.astype(jnp.int32), mode="drop"))
+                continue
+            with jax.named_scope("fct.hop"):
+                w = dmask.astype(acc)
+                for _, g in ups[i]:
+                    w = w * g
+                nums.append(jnp.zeros((dom,), acc).at[dkeys[0]].add(
+                    w, mode="drop"))
         probes = [nums[i][fkeys[i]].astype(acc) for i in range(m)]
         fvalid = fmask.astype(acc)
         vol_fact = fvalid
         for pr in probes:
             vol_fact = vol_fact * pr
-        dim_vols = []
-        for i in range(m):
+        dim_vols = [None] * len(routed_dims)
+        for j, i in enumerate(top):
             others = fvalid
-            for j in range(m):
-                if j != i:
-                    others = others * probes[j]
-            contrib = jnp.zeros((domains[i],), acc).at[fkeys[i]].add(
+            for k in range(m):
+                if k != j:
+                    others = others * probes[k]
+            contrib = jnp.zeros((domains[i],), acc).at[fkeys[j]].add(
                 others, mode="drop")
             (dtext, dkeys, dmask) = routed_dims[i]
-            dim_vols.append(contrib[dkeys] * dmask.astype(acc))
+            if i not in ups:
+                dim_vols[i] = contrib[dkeys] * dmask.astype(acc)
+                continue
+            base = contrib[dkeys[0]] * dmask.astype(acc)
+            vol = base
+            for _, g in ups[i]:
+                vol = vol * g
+            dim_vols[i] = vol
+            # downward: the parent rows' volumes without each sub-dim
+            with jax.named_scope("fct.hop"):
+                for c, _ in ups[i]:
+                    rest = base
+                    for c2, g in ups[i]:
+                        if c2 != c:
+                            rest = rest * g
+                    (ctext, ckeys, cmask) = routed_dims[c]
+                    down = jnp.zeros((domains[c],), acc).at[
+                        dkeys[links[c][1]]].add(rest, mode="drop")
+                    dim_vols[c] = down[ckeys] * cmask.astype(acc)
     return vol_fact, dim_vols
 
 
 def _device_fct_local(fact, dims, *, domains: Tuple[int, ...], vocab: int,
                       histogram_backend: str,
-                      accum: Optional[AccumPolicy] = None):
+                      accum: Optional[AccumPolicy] = None,
+                      links: Tuple[Tuple[int, int], ...] = ()):
     """One worker's MR¹+MR² for one CN, WITHOUT the final cross-worker psum
     (the runtime engine vmaps this over a batch of CNs and psums once).
 
     ``accum`` pins the volume/histogram dtype (int32-checked or int64-exact);
     integer weights of either width ride the integer-exact fct_count kernel
-    on the pallas path."""
+    on the pallas path.  ``links`` marks hop sub-dimensions
+    (:func:`_mr1_volumes`)."""
     routed_fact, routed_dims = _route_cn(fact, dims)
     vol_fact, dim_vols = _mr1_volumes(routed_fact, routed_dims, domains,
-                                      accum)
+                                      accum, links)
     ftext = routed_fact[0]
 
     # --- MR2: weighted histograms + global aggregation ---
@@ -194,8 +248,18 @@ def _plan_to_arrays(plan: CNPlan):
     return arrays(plan.fact), [arrays(plan.dims[i]) for i in plan.included]
 
 
+def _refuse_hop(plan: CNPlan, what: str) -> None:
+    """Only the engine's store-path families run a hop: the seed and
+    two-job programs take a star's columns."""
+    if plan.hops:
+        raise NotImplementedError(
+            f"{what} runs star CNs only; a CN with a sub-dimension hop runs "
+            "through FCTEngine with a RelationStore (an FCTSession)")
+
+
 def make_fct_program(plan: CNPlan, mesh: Mesh, histogram_backend: str = "auto"):
     """shard_map'ed (fact, dims) -> freq[vocab], plus its input arrays."""
+    _refuse_hop(plan, "make_fct_program")
     fact, dims = _plan_to_arrays(plan)
     domains = tuple(plan.key_domains[i] for i in plan.included)
     shard = P("w")
@@ -267,6 +331,7 @@ def run_cn_plan_two_jobs(plan: CNPlan, mesh: Mesh,
     """
     from repro.runtime.batch import pad_plan_arrays, plan_signature, x64_flag
     from repro.runtime.cache import default_cache
+    _refuse_hop(plan, "run_cn_plan_two_jobs")
     if cache is None:
         cache = default_cache()
     sig = plan_signature(plan)

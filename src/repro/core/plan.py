@@ -74,7 +74,8 @@ class RelationRef:
     must go through the serving layer's ``invalidate`` hooks.
     """
 
-    role: str                            # "fact" | "dim"
+    role: str                            # "fact" | "dim" | "parent" (a
+    #                                      dim with FKs to its sub-dims)
     name: str                            # base relation name
     rows: np.ndarray                     # tuple-set row indices into the base
     base_text: np.ndarray                # [R, L] shared reference, not a copy
@@ -168,15 +169,17 @@ class RelationRef:
         Text is padded to ``[P, text_pad, rows_pad]`` with PAD_ID; keys are
         FULL-width for the fact (``[P, m_all, rows_pad]`` — the engine's
         device program selects each CN's columns with a small gathered
-        index, so one upload serves every CN over this tuple set) and
-        ``[P, rows_pad]`` for a dim.  Padded rows are never named by any
-        send table, so the fill values are semantics-free.
+        index, so one upload serves every CN over this tuple set), its own
+        key then its FKs to its sub-dims for a hop's parent
+        (``[P, 1 + n_sub, rows_pad]``) and ``[P, rows_pad]`` for a dim.
+        Padded rows are never named by any send table, so the fill values
+        are semantics-free.
         """
         text = self.text_shards()
         P, S, L = text.shape
         text = np.pad(text, ((0, 0), (0, rows_pad - S), (0, text_pad - L)),
                       constant_values=PAD_ID)
-        if self.role == "fact":
+        if self.role != "dim":
             keys = self.fact_key_shards(range(self.key_width))
             keys = np.pad(keys, ((0, 0), (0, rows_pad - S), (0, 0)),
                           constant_values=0)
@@ -233,6 +236,10 @@ class CNPlan:
     #: mass (``core.star.cn_volume_mass``).  inf = unknown (never pruned);
     #: 0.0 = provably contributes nothing, safe to skip bit-exactly.
     contrib_bound: float = float("inf")
+    #: hop sub-dimensions: dim index -> (parent dim index, row of the
+    #: parent's stored key matrix that holds the foreign key to it).
+    #: Empty for a star CN.
+    hops: Dict[int, Tuple[int, int]] = dataclasses.field(default_factory=dict)
 
     @property
     def n_devices(self) -> int:
@@ -284,7 +291,15 @@ def build_cn_plan(schema: StarSchema, ts: TupleSets, cn: StarCN,
                   n_devices: int, mode: str = "uniform", rho: int = 4,
                   sample_frac: float = 1.0, salt: int = 0,
                   shares: Optional[Tuple[int, ...]] = None) -> Optional[CNPlan]:
-    """Routing plan for a joined star CN.  Returns None for 1-relation CNs.
+    """Routing plan for a joined CN.  Returns None for 1-relation CNs.
+
+    The CN's root — the fact, or for a CN without it the dimension its
+    sub-dimensions hang off — is routed as a star's fact: hashed over the
+    task grid of the dimensions directly below it.  A sub-dimension of
+    one of those dimensions (a hop: CUSTOMER behind ORDERS) is replicated
+    to every device, so its num-array is whole wherever a referencing
+    parent row lands; its parent's stored keys carry the foreign keys to
+    its sub-dimensions beside its own key.
 
     ``mode="adaptive"`` is the balance pass: instead of the caller's fixed
     ``rho``, the over-decomposition factor is chosen per CN from the
@@ -297,34 +312,48 @@ def build_cn_plan(schema: StarSchema, ts: TupleSets, cn: StarCN,
     """
     P = n_devices
     fact_idx, dim_idx = ts.cn_rows(cn)
-    if fact_idx is None or len(dim_idx) == 0:
+    if len(dim_idx) + (fact_idx is not None) < 2:
         return None
-    inc = tuple(sorted(dim_idx))
+    parents = schema.parents
+    if fact_idx is not None:
+        root = schema.fact
+        root_rows = fact_idx
+        root_fks = tuple(schema.fact_keys(i) for i in range(schema.m))
+        fk_pos = {i: i for i in range(schema.m)}
+    else:
+        r, = [i for i in dim_idx if parents[i] not in dim_idx]
+        root = schema.dims[r]
+        root_rows = dim_idx.pop(r)
+        kids = schema.children(r)
+        root_fks = tuple(schema.parent_fk(c) for c in kids)
+        fk_pos = {c: j for j, c in enumerate(kids)}
+    inc = tuple(sorted(i for i in dim_idx if i in fk_pos))
+    hop = tuple(sorted(i for i in dim_idx if i not in fk_pos))
     m = len(inc)
 
     # --- shares (§4.1): optimizer over the CN's tuple-set sizes ---
     rho_eff = 1 if mode == "uniform" else rho
     sizes = [max(1, len(dim_idx[i])) for i in inc]
     if mode == "adaptive":
-        rho_eff = choose_rho(len(fact_idx), P)
+        rho_eff = choose_rho(len(root_rows), P)
         if shares is None:
             # re-optimize shares for the FULL task grid (T = rho * P) rather
             # than over-decomposing a P-share solution: the divisor lattice
             # of T is richer, so the grid tracks the size ratios closer
             grid_shares = optimize_shares(sizes, P * rho_eff,
-                                          fact_size=len(fact_idx)).shares
+                                          fact_size=len(root_rows)).shares
         else:
             grid_shares = over_decompose(shares, rho_eff)
     else:
         if shares is None:
-            shares = optimize_shares(sizes, P, fact_size=len(fact_idx)).shares
+            shares = optimize_shares(sizes, P, fact_size=len(root_rows)).shares
         grid_shares = shares if mode == "uniform" else over_decompose(shares,
                                                                       rho)
     grid = TaskGrid(grid_shares)
     T = grid.n_tasks
 
     # --- per-row task/bucket assignment (host 'getPartition()') ---
-    fact_key_cols = [schema.fact_keys(i)[fact_idx] for i in inc]
+    fact_key_cols = [root_fks[fk_pos[i]][root_rows] for i in inc]
     fact_tasks = grid.fact_tasks(fact_key_cols, salt)
     dim_buckets = {i: grid.dim_buckets(p, schema.dim_keys(i)[dim_idx[i]], salt)
                    for p, i in enumerate(inc)}
@@ -362,30 +391,42 @@ def build_cn_plan(schema: StarSchema, ts: TupleSets, cn: StarCN,
     # --- fact routing: each row to exactly one device ---
     fact_dst = t2d[fact_tasks]
     keep = fact_dst >= 0
-    fact_ref = RelationRef(role="fact", name=schema.fact.name, rows=fact_idx,
-                           base_text=schema.fact.text,
-                           base_keys=tuple(schema.fact_keys(i)
-                                           for i in range(schema.m)),
-                           n_devices=P, base_chunks=schema.fact.chunks)
+    fact_ref = RelationRef(role="fact", name=root.name, rows=root_rows,
+                           base_text=root.text, base_keys=root_fks,
+                           n_devices=P, base_chunks=root.chunks)
     S_f = fact_ref.shard_rows
-    rows = np.arange(len(fact_idx))
+    rows = np.arange(len(root_rows))
     src = (rows // S_f).astype(np.int32)
     local = (rows % S_f).astype(np.int32)
     table, sent_f = _send_table(src[keep], fact_dst[keep].astype(np.int32),
                                 local[keep], P)
     fact_route = RelationRoute(ref=fact_ref, send=table, sent_rows=sent_f,
-                               key_cols=inc)
+                               key_cols=tuple(fk_pos[i] for i in inc))
 
     # --- dim routing: each row to every device owning a matching task ---
     dims: Dict[int, RelationRoute] = {}
     shuffle_rows = sent_f
     shuffle_bytes = sent_f * 4 * (fact_ref.text_len + m)
+    hops: Dict[int, Tuple[int, int]] = {}
+    for c in hop:
+        p_dim = parents[c]
+        hops[c] = (p_dim, 1 + schema.children(p_dim).index(c))
     for p, i in enumerate(inc):
         rows_i = dim_idx[i]
-        dim_ref = RelationRef(role="dim", name=schema.dims[i].name,
-                              rows=rows_i, base_text=schema.dims[i].text,
-                              base_keys=(schema.dim_keys(i),), n_devices=P,
-                              base_chunks=schema.dims[i].chunks)
+        if any(hops[c][0] == i for c in hop):
+            # a hop's parent: its own key, then its FKs to its sub-dims
+            dim_ref = RelationRef(
+                role="parent", name=schema.dims[i].name, rows=rows_i,
+                base_text=schema.dims[i].text,
+                base_keys=(schema.dim_keys(i),) + tuple(
+                    schema.parent_fk(c) for c in schema.children(i)),
+                n_devices=P, base_chunks=schema.dims[i].chunks)
+        else:
+            dim_ref = RelationRef(role="dim", name=schema.dims[i].name,
+                                  rows=rows_i, base_text=schema.dims[i].text,
+                                  base_keys=(schema.dim_keys(i),),
+                                  n_devices=P,
+                                  base_chunks=schema.dims[i].chunks)
         S_d = dim_ref.shard_rows
         r = np.arange(len(rows_i))
         src_d = (r // S_d).astype(np.int32)
@@ -414,13 +455,29 @@ def build_cn_plan(schema: StarSchema, ts: TupleSets, cn: StarCN,
             table_d, sent_d = np.full((P, P, 1), -1, np.int32), 0
         dims[i] = RelationRoute(ref=dim_ref, send=table_d, sent_rows=sent_d)
         shuffle_rows += sent_d
-        shuffle_bytes += sent_d * 4 * (dim_ref.text_len + 1)
+        shuffle_bytes += sent_d * 4 * (dim_ref.text_len + dim_ref.key_width)
+
+    # --- hop routing: a sub-dimension's rows to every device ---
+    for c in hop:
+        rows_c = dim_idx[c]
+        ref = RelationRef(role="dim", name=schema.dims[c].name, rows=rows_c,
+                          base_text=schema.dims[c].text,
+                          base_keys=(schema.dim_keys(c),), n_devices=P,
+                          base_chunks=schema.dims[c].chunks)
+        r = np.arange(len(rows_c))
+        table_c, sent_c = _send_table(
+            np.repeat((r // ref.shard_rows).astype(np.int32), P),
+            np.tile(np.arange(P, dtype=np.int32), len(rows_c)),
+            np.repeat((r % ref.shard_rows).astype(np.int32), P), P)
+        dims[c] = RelationRoute(ref=ref, send=table_c, sent_rows=sent_c)
+        shuffle_rows += sent_c
+        shuffle_bytes += sent_c * 4 * (ref.text_len + 1)
 
     device_rows = np.bincount(fact_dst[keep], minlength=P).astype(np.int64)
-    return CNPlan(cn=cn, included=inc, shares=grid_shares, schedule=schedule,
-                  fact=fact_route, dims=dims,
-                  key_domains={i: schema.key_domain(i) for i in inc},
+    return CNPlan(cn=cn, included=inc + hop, shares=grid_shares,
+                  schedule=schedule, fact=fact_route, dims=dims,
+                  key_domains={i: schema.key_domain(i) for i in inc + hop},
                   vocab_size=schema.vocab_size,
                   shuffle_rows=shuffle_rows, shuffle_bytes=shuffle_bytes,
                   rho=rho_eff, device_rows=device_rows,
-                  contrib_bound=cn_volume_mass(schema, ts, cn))
+                  contrib_bound=cn_volume_mass(schema, ts, cn), hops=hops)

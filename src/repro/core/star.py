@@ -4,7 +4,8 @@
                       Eq. 1–3 taken literally).  Exponential; tests only.
 ``fct_star``        — the star method of Tao & Yu [12] (the paper's §3
                       starting point): join-free frequency computation via
-                      num-arrays and volumes.  This is the correctness oracle
+                      num-arrays and volumes, passed up and down a CN's
+                      join tree on a snowflake.  This is the correctness oracle
                       for the distributed engine and the "single machine"
                       baseline of the paper's §6.1 comparison.
 Both return an int64 frequency vector over the vocabulary (query keywords and
@@ -12,12 +13,12 @@ PAD included — callers mask before top-k, matching Def. 6's "not in q").
 """
 from __future__ import annotations
 
-import itertools
-from typing import Sequence
+from typing import Dict, List, Sequence
 
 import numpy as np
 
-from repro.core.candidate_network import StarCN, TupleSets, enumerate_star_cns, prune_empty_cns
+from repro.core.candidate_network import (StarCN, TupleSets, enumerate_cns,
+                                          prune_empty_cns)
 from repro.data.schema import PAD_ID, StarSchema, tokens_histogram
 
 
@@ -25,12 +26,104 @@ def _row_histogram(text_rows: np.ndarray, vocab: int) -> np.ndarray:
     return tokens_histogram(text_rows, np.ones(text_rows.shape[0], np.int64), vocab)
 
 
+def schema_cns(schema: StarSchema, n_keywords: int, r_max: int) -> List[StarCN]:
+    """Every CN of the schema's tree (a star's list for a star)."""
+    return enumerate_cns(n_keywords, schema.parents, r_max)
+
+
+class _Tree:
+    """One CN's join tree over its tuple sets: the root (-1 for the fact,
+    else a dimension), each node's rows, children, and the foreign keys
+    that join a node's rows to a child's key."""
+
+    def __init__(self, schema: StarSchema, fact_idx, dim_idx) -> None:
+        self.schema = schema
+        par = schema.parents
+        self.rows: Dict[int, np.ndarray] = dict(dim_idx)
+        if fact_idx is not None:
+            self.root = -1
+            self.rows[-1] = fact_idx
+        else:
+            self.root, = [i for i in dim_idx if par[i] not in dim_idx]
+        self.kids = {v: [c for c in sorted(dim_idx)
+                         if c != self.root and par[c] == v]
+                     for v in self.rows}
+
+    def text(self, v: int) -> np.ndarray:
+        rel = self.schema.fact if v < 0 else self.schema.dims[v]
+        return rel.text[self.rows[v]]
+
+    def fk(self, v: int, c: int) -> np.ndarray:
+        """Node ``v``'s rows' foreign key into child ``c``."""
+        col = (self.schema.fact_keys(c) if v < 0
+               else self.schema.parent_fk(c))
+        return col[self.rows[v]]
+
+    def pk(self, c: int) -> np.ndarray:
+        return self.schema.dim_keys(c)[self.rows[c]]
+
+    def weights(self, dtype) -> Dict[int, np.ndarray]:
+        """Per node, each tuple-set row's number of joined trees (its
+        volume): an upward pass of num-arrays (rows per key, each row
+        weighted by its own subtree's completions), then a downward pass
+        of the completions outside each subtree, scatter-added into the
+        child's key domain and gathered at its rows."""
+        below: Dict[int, np.ndarray] = {}
+        num: Dict[int, np.ndarray] = {}
+        probe: Dict[tuple, np.ndarray] = {}
+
+        def up(v: int) -> None:
+            b = np.ones(len(self.rows[v]), dtype)
+            for c in self.kids[v]:
+                up(c)
+                dom = self.schema.key_domain(c)
+                num[c] = _scatter(self.pk(c), below[c], dom, dtype)
+                probe[(v, c)] = num[c][self.fk(v, c)]
+                b = b * probe[(v, c)]
+            below[v] = b
+
+        out = {}
+
+        def down(v: int, above: np.ndarray) -> None:
+            out[v] = above * below[v]
+            for c in self.kids[v]:
+                rest = above.copy()
+                for c2 in self.kids[v]:
+                    if c2 != c:
+                        rest = rest * probe[(v, c2)]
+                by_key = _scatter(self.fk(v, c), rest,
+                                  self.schema.key_domain(c), dtype)
+                down(c, by_key[self.pk(c)])
+
+        up(self.root)
+        down(self.root, np.ones(len(self.rows[self.root]), dtype))
+        return out
+
+    def results(self, v: int, r: int):
+        """Bruteforce: every joined tree below node ``v`` at row ``r``, as
+        a list of (node, row) pairs."""
+        trees = [[(v, r)]]
+        for c in self.kids[v]:
+            key = int((self.schema.fact_keys(c) if v < 0
+                       else self.schema.parent_fk(c))[r])
+            pk = self.schema.dim_keys(c)
+            opts = [t for s in self.rows[c] if int(pk[s]) == key
+                    for t in self.results(c, int(s))]
+            trees = [a + b for a in trees for b in opts]
+        return trees
+
+
+def _scatter(keys: np.ndarray, w: np.ndarray, dom: int, dtype) -> np.ndarray:
+    out = np.zeros((dom,), dtype)
+    np.add.at(out, keys, w)
+    return out
+
+
 def fct_bruteforce(schema: StarSchema, keywords: Sequence[int],
                    r_max: int) -> np.ndarray:
     """Materialize all MTJNTs of all CNs; count term occurrences."""
     ts = TupleSets.build(schema, keywords)
-    cns = prune_empty_cns(
-        enumerate_star_cns(len(keywords), schema.m, r_max), ts)
+    cns = prune_empty_cns(schema_cns(schema, len(keywords), r_max), ts)
     freq = np.zeros((schema.vocab_size,), np.int64)
     for cn in cns:
         freq += _bruteforce_cn(schema, ts, cn)
@@ -41,39 +134,16 @@ def fct_bruteforce(schema: StarSchema, keywords: Sequence[int],
 def _bruteforce_cn(schema: StarSchema, ts: TupleSets, cn: StarCN) -> np.ndarray:
     vocab = schema.vocab_size
     freq = np.zeros((vocab,), np.int64)
-    fact_idx, dim_idx = ts.cn_rows(cn)
-    if fact_idx is None:  # single-dimension CN: each qualifying row is a MTJNT
-        (i, rows), = dim_idx.items()
-        return _row_histogram(schema.dims[i].text[rows], vocab)
-    if len(dim_idx) == 0:  # fact-alone CN
-        return _row_histogram(schema.fact.text[fact_idx], vocab)
-    inc = sorted(dim_idx)
-    # group dim rows by join key
-    by_key = []
-    for i in inc:
-        rows = dim_idx[i]
-        keys = schema.dim_keys(i)[rows]
-        groups: dict = {}
-        for r, a in zip(rows, keys):
-            groups.setdefault(int(a), []).append(int(r))
-        by_key.append(groups)
-    for t in fact_idx:
-        choices = []
-        ok = True
-        for pos, i in enumerate(inc):
-            a = int(schema.fact_keys(i)[t])
-            rows = by_key[pos].get(a)
-            if not rows:
-                ok = False
-                break
-            choices.append(rows)
-        if not ok:
-            continue
-        fact_hist = _row_histogram(schema.fact.text[t:t + 1], vocab)
-        for combo in itertools.product(*choices):
-            freq += fact_hist
-            for pos, i in enumerate(inc):
-                freq += _row_histogram(schema.dims[i].text[combo[pos]:combo[pos] + 1], vocab)
+    tree = _Tree(schema, *ts.cn_rows(cn))
+    hist = {}
+    for r in tree.rows[tree.root]:
+        for result in tree.results(tree.root, int(r)):
+            for v, row in result:
+                if (v, row) not in hist:
+                    rel = schema.fact if v < 0 else schema.dims[v]
+                    hist[(v, row)] = _row_histogram(rel.text[row:row + 1],
+                                                    vocab)
+                freq += hist[(v, row)]
     return freq
 
 
@@ -81,8 +151,7 @@ def fct_star(schema: StarSchema, keywords: Sequence[int],
              r_max: int) -> np.ndarray:
     """Star method: freq(w) = Σ_CN Σ_tuples count(text, w) · vol(tuple)."""
     ts = TupleSets.build(schema, keywords)
-    cns = prune_empty_cns(
-        enumerate_star_cns(len(keywords), schema.m, r_max), ts)
+    cns = prune_empty_cns(schema_cns(schema, len(keywords), r_max), ts)
     freq = np.zeros((schema.vocab_size,), np.int64)
     for cn in cns:
         freq += star_cn_frequencies(schema, ts, cn)
@@ -92,40 +161,13 @@ def fct_star(schema: StarSchema, keywords: Sequence[int],
 
 def star_cn_frequencies(schema: StarSchema, ts: TupleSets,
                         cn: StarCN) -> np.ndarray:
-    """Join-free per-CN frequencies (Eq. 2 via num-arrays and volumes)."""
+    """Join-free per-CN frequencies (Eq. 2 via num-arrays and volumes), by
+    message passing over the CN's join tree (a star's is one level)."""
     vocab = schema.vocab_size
-    fact_idx, dim_idx = ts.cn_rows(cn)
-    if fact_idx is None:
-        (i, rows), = dim_idx.items()
-        return _row_histogram(schema.dims[i].text[rows], vocab)
-    if len(dim_idx) == 0:
-        return _row_histogram(schema.fact.text[fact_idx], vocab)
-    inc = sorted(dim_idx)
-    # num-arrays: per included dim, matches per join-key over its tuple set
-    nums = []
-    for i in inc:
-        dom = schema.key_domain(i)
-        keys = schema.dim_keys(i)[dim_idx[i]]
-        nums.append(np.bincount(keys, minlength=dom).astype(np.int64))
-    # fact volumes: vol(t) = Π_i num_i(key_i(t))
-    fkeys = [schema.fact_keys(i)[fact_idx] for i in inc]
-    per_dim_num = [nums[p][fkeys[p]] for p in range(len(inc))]
-    vol_fact = np.ones(len(fact_idx), np.int64)
-    for v in per_dim_num:
-        vol_fact *= v
-    freq = tokens_histogram(schema.fact.text[fact_idx], vol_fact, vocab)
-    # dim-row volumes: vol_i(a) = Σ_{t: key_i(t)=a} Π_{j≠i} num_j(key_j(t))
-    for p, i in enumerate(inc):
-        others = np.ones(len(fact_idx), np.int64)
-        for q in range(len(inc)):
-            if q != p:
-                others *= per_dim_num[q]
-        dom = schema.key_domain(i)
-        vol_by_key = np.zeros((dom,), np.int64)
-        np.add.at(vol_by_key, fkeys[p], others)
-        rows = dim_idx[i]
-        w = vol_by_key[schema.dim_keys(i)[rows]]
-        freq += tokens_histogram(schema.dims[i].text[rows], w, vocab)
+    tree = _Tree(schema, *ts.cn_rows(cn))
+    freq = np.zeros((vocab,), np.int64)
+    for v, w in tree.weights(np.int64).items():
+        freq += tokens_histogram(tree.text(v), w, vocab)
     return freq
 
 
@@ -142,37 +184,11 @@ def cn_volume_mass(schema: StarSchema, ts: TupleSets, cn: StarCN) -> float:
     zero, where products of nonnegative integers are exactly 0.0 iff a
     factor is zero.
     """
-    fact_idx, dim_idx = ts.cn_rows(cn)
-    if fact_idx is None:
-        (i, rows), = dim_idx.items()
-        return float(np.count_nonzero(schema.dims[i].text[rows] != PAD_ID))
-    if len(dim_idx) == 0:
-        return float(np.count_nonzero(schema.fact.text[fact_idx] != PAD_ID))
-    inc = sorted(dim_idx)
-    nums = []
-    for i in inc:
-        dom = schema.key_domain(i)
-        keys = schema.dim_keys(i)[dim_idx[i]]
-        nums.append(np.bincount(keys, minlength=dom).astype(np.float64))
-    fkeys = [schema.fact_keys(i)[fact_idx] for i in inc]
-    per_dim_num = [nums[p][fkeys[p]] for p in range(len(inc))]
-    vol_fact = np.ones(len(fact_idx), np.float64)
-    for v in per_dim_num:
-        vol_fact *= v
-    fact_tokens = (schema.fact.text[fact_idx] != PAD_ID).sum(axis=1)
-    mass = float(vol_fact @ fact_tokens.astype(np.float64))
-    for p, i in enumerate(inc):
-        others = np.ones(len(fact_idx), np.float64)
-        for q in range(len(inc)):
-            if q != p:
-                others *= per_dim_num[q]
-        dom = schema.key_domain(i)
-        vol_by_key = np.zeros((dom,), np.float64)
-        np.add.at(vol_by_key, fkeys[p], others)
-        rows = dim_idx[i]
-        w = vol_by_key[schema.dim_keys(i)[rows]]
-        dim_tokens = (schema.dims[i].text[rows] != PAD_ID).sum(axis=1)
-        mass += float(w @ dim_tokens.astype(np.float64))
+    tree = _Tree(schema, *ts.cn_rows(cn))
+    mass = 0.0
+    for v, w in tree.weights(np.float64).items():
+        tokens = (tree.text(v) != PAD_ID).sum(axis=1)
+        mass += float(w @ tokens.astype(np.float64))
     return mass
 
 

@@ -6,10 +6,12 @@ stored as
   * an int32 token matrix ``text[rows, text_len]`` (PAD_ID padded) holding the
     tokenized concatenation of all non-key attributes.
 
-A Schema describes a star (or snowflake, after pre-joining) layout: one fact
-relation joined to ``m`` dimension relations through (fact_col -> dim_col)
-foreign keys.  This mirrors the paper's experimental setup (LINEITEM fact;
-PART / SUPPLIER / ORDERS dimensions).
+A Schema describes a star or a one-level snowflake: one fact relation
+joined to ``m`` dimension relations through (fact_col -> dim_col) foreign
+keys, and optionally sub-dimensions, each joined to one of those dimensions
+through a (parent_col -> child_col) foreign key of the parent.  This mirrors
+the paper's experimental setup (LINEITEM fact; PART / SUPPLIER / ORDERS
+dimensions; CUSTOMER behind ORDERS for the chain and mix queries).
 """
 from __future__ import annotations
 
@@ -131,38 +133,104 @@ class JoinEdge:
     dim_col: str
 
 
+@dataclasses.dataclass(frozen=True)
+class SubEdge:
+    """parent.parent_col references child.child_col: a sub-dimension edge
+    (dimension -> dimension, FK -> PK), one level below the fact."""
+
+    child_name: str
+    parent_name: str
+    parent_col: str
+    child_col: str
+
+
 @dataclasses.dataclass
 class StarSchema:
-    """One fact relation + m dimensions; the paper's star candidate network."""
+    """One fact relation + m dimensions, and optional sub-dimensions.
+
+    ``dims[:m]`` are the fact's dimensions (``edges[i]`` joins the fact to
+    ``dims[i]``); ``dims[m:]`` are sub-dimensions, ``sub_edges[j]`` joining
+    ``dims[m + j]`` to its parent, which must be one of ``dims[:m]``.  A
+    star has no sub-edges.  The schema graph is a tree (every relation is
+    reached from the fact by one path), at most two hops deep.
+    """
 
     fact: Relation
     dims: Sequence[Relation]
     edges: Sequence[JoinEdge]  # edges[i] joins fact to dims[i]
     vocab_size: int
+    sub_edges: Sequence[SubEdge] = ()  # sub_edges[j] joins dims[m + j]
 
     def __post_init__(self) -> None:
-        assert len(self.dims) == len(self.edges)
+        assert len(self.dims) == len(self.edges) + len(self.sub_edges)
         for dim, edge in zip(self.dims, self.edges):
             assert dim.name == edge.dim_name
             d_fact = self.fact.key_domains[edge.fact_col]
             d_dim = dim.key_domains[edge.dim_col]
             assert d_fact == d_dim, (edge, d_fact, d_dim)
+        names = [self.fact.name] + [d.name for d in self.dims]
+        if len(set(names)) != len(names):
+            raise ValueError(f"relation names repeat: {names}")
+        top = {d.name: i for i, d in enumerate(self.dims[:self.m])}
+        for j, se in enumerate(self.sub_edges):
+            child = self.dims[self.m + j]
+            if child.name != se.child_name:
+                raise ValueError(
+                    f"sub_edges[{j}] names child {se.child_name!r} but "
+                    f"dims[{self.m + j}] is {child.name!r}")
+            if se.parent_name not in top:
+                raise ValueError(
+                    f"sub-dimension {se.child_name!r} hangs off "
+                    f"{se.parent_name!r}, which is not a dimension of the "
+                    f"fact: only one level of sub-dimensions is supported, "
+                    f"and the schema graph must be a tree")
+            parent = self.dims[top[se.parent_name]]
+            d_par = parent.key_domains[se.parent_col]
+            d_child = child.key_domains[se.child_col]
+            if d_par != d_child:
+                raise ValueError(f"{se}: parent key domain {d_par} != "
+                                 f"child key domain {d_child}")
 
     @property
     def m(self) -> int:
-        return len(self.dims)
+        """Number of the fact's own dimensions (sub-dimensions excluded)."""
+        return len(self.edges)
+
+    @property
+    def parents(self) -> Tuple[int, ...]:
+        """Per dimension: -1 for the fact's dimensions, the parent's index
+        in ``dims`` for a sub-dimension.  A star is all -1."""
+        top = {d.name: i for i, d in enumerate(self.dims[:self.m])}
+        return (-1,) * self.m + tuple(top[se.parent_name]
+                                      for se in self.sub_edges)
 
     def key_domain(self, i: int) -> int:
-        return self.fact.key_domains[self.edges[i].fact_col]
+        """Domain of dimension ``i``'s key (and of the FK that joins it)."""
+        if i < self.m:
+            return self.fact.key_domains[self.edges[i].fact_col]
+        return self.dims[i].key_domains[self.sub_edges[i - self.m].child_col]
 
     def fact_keys(self, i: int) -> np.ndarray:
         return self.fact.keys[self.edges[i].fact_col]
 
     def dim_keys(self, i: int) -> np.ndarray:
-        return self.dims[i].keys[self.edges[i].dim_col]
+        """Dimension ``i``'s own key column."""
+        if i < self.m:
+            return self.dims[i].keys[self.edges[i].dim_col]
+        return self.dims[i].keys[self.sub_edges[i - self.m].child_col]
+
+    def parent_fk(self, i: int) -> np.ndarray:
+        """The column of sub-dimension ``i``'s parent that references it."""
+        se = self.sub_edges[i - self.m]
+        return self.dims[self.parents[i]].keys[se.parent_col]
+
+    def children(self, i: int) -> Tuple[int, ...]:
+        """Sub-dimensions of dimension ``i``, in ``dims`` order."""
+        return tuple(c for c, p in enumerate(self.parents) if p == i)
 
     def relation_role(self, name: str) -> Tuple[str, int]:
-        """("fact", -1) or ("dim", i) for a relation name; KeyError else."""
+        """("fact", -1) or ("dim", i) for a relation name (``i >= m`` for a
+        sub-dimension); KeyError else."""
         if name == self.fact.name:
             return "fact", -1
         for i, dim in enumerate(self.dims):
@@ -182,31 +250,41 @@ class StarSchema:
 
         A dimension append may introduce primary-key values past the current
         domain (new dim rows ARE new keys); the domain grows to cover them
-        and the fact's matching foreign-key domain grows in lockstep (the
-        schema invariant ``d_fact == d_dim``) — its column arrays are still
-        shared, only the metadata dict is replaced.  Fact appends must
-        reference existing dimension keys.
+        and the referencing foreign-key domain — the fact's, or a
+        sub-dimension's parent's — grows in lockstep (the schema invariant
+        ``d_fk == d_pk``); the referencing relation's column arrays are
+        still shared, only its metadata dict is replaced.  Appends must
+        reference existing keys through their own foreign keys.
         """
         role, i = self.relation_role(name)
         if role == "fact":
-            return StarSchema(fact=self.fact.append_rows(keys, text),
-                              dims=self.dims, edges=self.edges,
-                              vocab_size=self.vocab_size)
-        edge = self.edges[i]
+            return dataclasses.replace(
+                self, fact=self.fact.append_rows(keys, text))
+        if i < self.m:
+            pk_col, fk_col = self.edges[i].dim_col, self.edges[i].fact_col
+        else:
+            se = self.sub_edges[i - self.m]
+            pk_col, fk_col = se.child_col, se.parent_col
         dims = list(self.dims)
-        pk = np.asarray(keys[edge.dim_col]) if edge.dim_col in keys else None
-        new_dom = dims[i].key_domains[edge.dim_col]
+        pk = np.asarray(keys[pk_col]) if pk_col in keys else None
+        new_dom = dims[i].key_domains[pk_col]
         if pk is not None and pk.size:
             new_dom = max(new_dom, int(pk.max()) + 1)
         dims[i] = dims[i].append_rows(
-            keys, text, domain_overrides={edge.dim_col: new_dom})
+            keys, text, domain_overrides={pk_col: new_dom})
         fact = self.fact
-        if new_dom != fact.key_domains[edge.fact_col]:
-            fact = dataclasses.replace(
-                fact, key_domains={**fact.key_domains,
-                                   edge.fact_col: new_dom})
-        return StarSchema(fact=fact, dims=tuple(dims), edges=self.edges,
-                          vocab_size=self.vocab_size)
+        if i < self.m:
+            if new_dom != fact.key_domains[fk_col]:
+                fact = dataclasses.replace(
+                    fact, key_domains={**fact.key_domains,
+                                       fk_col: new_dom})
+        else:
+            p = self.parents[i]
+            if new_dom != dims[p].key_domains[fk_col]:
+                dims[p] = dataclasses.replace(
+                    dims[p], key_domains={**dims[p].key_domains,
+                                          fk_col: new_dom})
+        return dataclasses.replace(self, fact=fact, dims=tuple(dims))
 
 
 def keyword_mask(text: np.ndarray, keywords: Sequence[int]) -> np.ndarray:

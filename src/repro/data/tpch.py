@@ -1,9 +1,12 @@
 """Synthetic TPC-H-like dataset generator (the paper's benchmark layout).
 
 LINEITEM is the fact relation; PART, SUPPLIER and ORDERS are dimensions
-(the paper links PART and SUPPLIER directly to LINEITEM, §6.1).  CUSTOMER is
-generated too so the chain-type queries can pre-join CUSTOMER⋈ORDERS exactly
-as the paper does for Q4–Q9.
+(the paper links PART and SUPPLIER directly to LINEITEM, §6.1).
+``generate_snowflake`` adds CUSTOMER one hop behind ORDERS (ORDERS gains
+``custkey``), which the schema, planner and device path serve natively.
+CUSTOMER alone (``generate_customer``) and ``prejoin_orders_customer`` stay
+for the CPU-era benchmarks, which glue CUSTOMER's text onto ORDERS the way
+the paper's chain queries (Q4–Q9) are set up.
 
 Two key-frequency modes:
   * ``skew=0``  — foreign keys drawn uniformly (the §4.1 assumption),
@@ -16,7 +19,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.data.schema import PAD_ID, JoinEdge, Relation, StarSchema
+from repro.data.schema import PAD_ID, JoinEdge, Relation, StarSchema, SubEdge
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,7 +106,7 @@ def generate(cfg: TpchConfig) -> StarSchema:
 
 
 def generate_customer(cfg: TpchConfig) -> Relation:
-    """CUSTOMER relation for chain-type queries (pre-joined with ORDERS)."""
+    """CUSTOMER relation (the sub-dimension of :func:`generate_snowflake`)."""
     rng = np.random.default_rng(cfg.seed + 1)
     nc = cfg.rows(cfg.cust_rows)
     return Relation(
@@ -112,6 +115,26 @@ def generate_customer(cfg: TpchConfig) -> Relation:
         key_domains={"custkey": nc},
         text=_text(rng, nc, cfg.text_len, cfg.vocab_size),
     )
+
+
+def generate_snowflake(cfg: TpchConfig) -> StarSchema:
+    """The star of :func:`generate` with CUSTOMER as a sub-dimension of
+    ORDERS: each order's ``custkey`` drawn uniformly over the customers."""
+    star = generate(cfg)
+    customer = generate_customer(cfg)
+    nc = customer.rows
+    rng = np.random.default_rng(cfg.seed + 2)
+    orders = star.dims[2]
+    orders = Relation(
+        orders.name,
+        keys={**orders.keys, "custkey": rng.integers(
+            0, nc, size=orders.rows, dtype=np.int64).astype(np.int32)},
+        key_domains={**orders.key_domains, "custkey": nc},
+        text=orders.text)
+    return StarSchema(
+        fact=star.fact, dims=[star.dims[0], star.dims[1], orders, customer],
+        edges=star.edges, vocab_size=cfg.vocab_size,
+        sub_edges=[SubEdge("CUSTOMER", "ORDERS", "custkey", "custkey")])
 
 
 def prejoin_orders_customer(orders: Relation, customer: Relation,
@@ -158,4 +181,5 @@ def plant_keywords(schema: StarSchema, keywords_per_relation: dict,
         if d.name in keywords_per_relation:
             dims[i] = plant(d, keywords_per_relation[d.name])
     return StarSchema(fact=fact, dims=dims, edges=schema.edges,
-                      vocab_size=schema.vocab_size)
+                      vocab_size=schema.vocab_size,
+                      sub_edges=schema.sub_edges)

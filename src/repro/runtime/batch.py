@@ -62,13 +62,22 @@ class RelationSig:
     ``key_width`` is the fact relation's FULL key-column count (0 for dims):
     the store-path device program takes the full-width stored key matrix
     [P, key_width, rows] plus a per-CN column-index gather, so its shapes —
-    and hence the executable-cache key — depend on it."""
+    and hence the executable-cache key — depend on it.  A hop's parent
+    dimension stores its own key and its FKs to its sub-dimensions, so its
+    ``key_width`` is their count.
+
+    ``parent`` marks a hop sub-dimension: the slot (index into
+    ``PlanSignature.dims``) of the dimension it hangs off, whose stored key
+    row ``parent_key`` holds the foreign key to it; -1 for a dimension of
+    the root."""
 
     rows: int
     cap: int
     text_len: int
     domain: int = 0
     key_width: int = 0
+    parent: int = -1
+    parent_key: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,9 +107,15 @@ class PlanSignature:
     def m(self) -> int:
         return len(self.dims)
 
+    @property
+    def has_hop(self) -> bool:
+        """Does any CN slot carry a sub-dimension behind its parent?"""
+        return any(d.parent >= 0 for d in self.dims)
+
 
 def _route_sig(route: RelationRoute, domain: int, bucket: bool,
-               key_width: int = 0) -> RelationSig:
+               key_width: int = 0, parent: int = -1,
+               parent_key: int = 0) -> RelationSig:
     # descriptor metadata only — computing a signature must not materialize
     # the (lazy) column arrays
     S, L = route.ref.shard_rows, route.ref.text_len
@@ -109,7 +124,8 @@ def _route_sig(route: RelationRoute, domain: int, bucket: bool,
         S, C, L = bucket_pow2(S), bucket_pow2(C), bucket_pow2(L)
         domain = bucket_pow2(domain) if domain else 0
     return RelationSig(rows=S, cap=C, text_len=L, domain=domain,
-                       key_width=key_width)
+                       key_width=key_width, parent=parent,
+                       parent_key=parent_key)
 
 
 def plan_signature(plan: CNPlan, bucket: bool = True,
@@ -118,8 +134,18 @@ def plan_signature(plan: CNPlan, bucket: bool = True,
     (``AccumPolicy.current()``); sessions pass their resolved policy."""
     if accum is None:
         accum = AccumPolicy.current()
-    dims = tuple(_route_sig(plan.dims[i], plan.key_domains[i], bucket)
-                 for i in plan.included)
+
+    def dim_sig(i):
+        route = plan.dims[i]
+        if i in plan.hops:
+            parent, row = plan.hops[i]
+            return _route_sig(route, plan.key_domains[i], bucket,
+                              parent=plan.included.index(parent),
+                              parent_key=row)
+        width = route.ref.key_width if route.ref.role == "parent" else 0
+        return _route_sig(route, plan.key_domains[i], bucket, width)
+
+    dims = tuple(dim_sig(i) for i in plan.included)
     fact = _route_sig(plan.fact, 0, bucket,
                       key_width=plan.fact.ref.key_width)
     return PlanSignature(n_devices=plan.n_devices, vocab=plan.vocab_size,

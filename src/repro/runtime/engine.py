@@ -107,11 +107,13 @@ def _vmapped_cns(fact, dims, sig: PlanSignature, histogram_backend: str,
     bit-identical totals under either AccumPolicy."""
     from repro.core.fct import _device_fct_local
     domains = tuple(d.domain for d in sig.dims)
+    links = tuple((d.parent, d.parent_key) for d in sig.dims) \
+        if sig.has_hop else ()
 
     def one_cn(f, ds):
         return _device_fct_local(f, ds, domains=domains, vocab=sig.vocab,
                                  histogram_backend=histogram_backend,
-                                 accum=sig.accum)
+                                 accum=sig.accum, links=links)
 
     hists = jax.vmap(one_cn)(fact, dims)            # [N, vocab]
     acc = sig.accum.dtype
@@ -158,8 +160,10 @@ def _build_batched_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
 
     Under ``reduce_scatter`` the vocab axis is padded to a multiple of P and
     sharded ``P("w")`` on the output instead of replicated (see
-    ``_vmapped_cns``); collection slices the pad bins off.
+    ``_vmapped_cns``); collection slices the pad bins off.  A signature
+    with a sub-dimension hop runs on the store family only.
     """
+    _refuse_hop(sig)
     shard = P(None, "w")
     spec = {"text": shard, "keys": shard, "send": shard}
 
@@ -174,6 +178,20 @@ def _build_batched_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
     return shard_map(device_fn, mesh=mesh, in_specs=(spec, [spec] * sig.m),
                      out_specs=_out_spec(reduce_cns, reduce_scatter),
                      check_vma=False)
+
+
+def _refuse_hop(sig: PlanSignature) -> None:
+    if sig.has_hop:
+        raise NotImplementedError(
+            "the host-stacked fct_batched families run star CNs only; a CN "
+            "with a sub-dimension hop needs a RelationStore (store=...)")
+
+
+def hop_rows(sig: PlanSignature, n_stack: int) -> int:
+    """Padded rows the ``fct.hop`` stage of one dispatch reads on each
+    device: per CN slot, every hop's routed parent and sub-dimension."""
+    return n_stack * sig.n_devices * sum(
+        sig.dims[d.parent].cap + d.cap for d in sig.dims if d.parent >= 0)
 
 
 def _build_store_fn(sig: PlanSignature, mesh: Mesh, histogram_backend: str,
@@ -370,6 +388,9 @@ class FCTEngine:
         # relations (fact and dims, per CN slot) whose route is the identity
         # on a one-device mesh (core.fct._route): no gather, no all_to_all
         self._c_in_place = self.metrics.counter("engine.routes_in_place")
+        # padded rows the fct.hop stage reads (hop_rows): a sub-dimension
+        # and its parent, per CN slot of each dispatch
+        self._c_hop_rows = self.metrics.counter("engine.hop_rows")
         self._c_bytes = self.metrics.counter("engine.bytes_shipped")
         self._c_column_bytes = self.metrics.counter(
             "engine.column_bytes_shipped")
@@ -469,6 +490,7 @@ class FCTEngine:
                                              reduce_scatter=rs))
             self._c_bytes.inc(shipped)
         else:
+            _refuse_hop(sig)
             with obs_span("store.send_tables", n_stack=n_stack):
                 fact, dims = stack_group(group, sig)
                 if n_stack > len(group):
@@ -485,10 +507,13 @@ class FCTEngine:
                 d["send"].nbytes for d in dims)
             self._c_bytes.inc(shipped)
             self._c_column_bytes.inc(columns)
-        with obs_span("engine.enqueue", kind=kind):
+        hop = hop_rows(sig, n_stack)
+        with obs_span("engine.enqueue", kind=kind, hop_rows=hop):
             out = fn(fact, dims)
         self._c_batches.inc()
         self._c_cns.inc(len(group))
+        if hop:
+            self._c_hop_rows.inc(hop)
         if sig.n_devices == 1:
             self._c_in_place.inc(n_stack * (1 + sig.m))
         return out
@@ -730,14 +755,14 @@ class FCTEngine:
     def stats(self) -> dict:
         out = self.cache.stats()
         (batches, cns, shipped, columns, d2h, g_pruned, rows_pruned,
-         in_place) = self.metrics.values(
+         in_place, hop) = self.metrics.values(
             self._c_batches, self._c_cns, self._c_bytes,
             self._c_column_bytes, self._c_d2h, self._c_groups_pruned,
-            self._c_pruned_rows, self._c_in_place)
+            self._c_pruned_rows, self._c_in_place, self._c_hop_rows)
         out.update(batches_run=batches, cns_run=cns, bytes_shipped=shipped,
                    column_bytes_shipped=columns, device_to_host_bytes=d2h,
                    groups_pruned=g_pruned, pruned_rows=rows_pruned,
-                   routes_in_place=in_place)
+                   routes_in_place=in_place, hop_rows=hop)
         return out
 
 

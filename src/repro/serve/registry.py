@@ -4,7 +4,8 @@ The ROADMAP multi-schema item: ``fct_serve`` used to bind ONE schema; a
 production gateway serves many tenants, each a loaded dataset with its own
 :class:`repro.api.FCTSession`.  The registry owns that mapping:
 
-  * ``register(name, source)`` accepts a built :class:`StarSchema` or a
+  * ``register(name, source)`` accepts a built :class:`StarSchema` (a star
+    or a snowflake with sub-dimensions) or a
     :class:`repro.data.tpch.TpchConfig` (generated lazily — registering a
     dataset costs nothing until its first query),
   * ``session(name)`` lazily constructs the tenant's FCTSession on first

@@ -112,9 +112,12 @@ def _store_args(sig: PlanSignature, mesh: Mesh, n_stack: int):
                                          jnp.int32, sharding=send)}
 
     fact = rel(sig.fact, (sig.fact.key_width,))
-    fact["cols"] = jax.ShapeDtypeStruct((n_stack, sig.m), jnp.int32,
+    n_top = sum(d.parent < 0 for d in sig.dims)
+    fact["cols"] = jax.ShapeDtypeStruct((n_stack, n_top), jnp.int32,
                                         sharding=NamedSharding(mesh, P()))
-    return fact, [rel(d, ()) for d in sig.dims]
+    # a hop's parent stores its own key and its FKs to its sub-dimensions
+    return fact, [rel(d, (d.key_width,) if d.key_width else ())
+                  for d in sig.dims]
 
 
 def _lower_store(sig: PlanSignature, mesh: Mesh, n_stack: int,
@@ -192,14 +195,15 @@ def test_fct_store_star_program_shards_over_v5e_2x2(topo):
 
 
 _STAGE = re.compile(r"\bfct\.(stack|route|mr1|mr2|reduce|topk|collective)\b")
+_HOP = re.compile(r"\bfct\.(hop)\b")
 _CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
 
 
-def _entry_op_stages(text: str) -> dict:
+def _entry_op_stages(text: str, stage=_STAGE) -> dict:
     """Per instruction of a compiled module's entry computation: its opcode
     (a fusion's kind), the innermost ``fct.*`` stage of its own
     ``op_name`` (None without one), and the stages named inside the
-    computations it calls, transitively."""
+    computations it calls, transitively (``stage``: which scopes count)."""
     comps, cur, entry = {}, None, None
     for line in text.splitlines():
         head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(", line)
@@ -215,7 +219,7 @@ def _entry_op_stages(text: str) -> dict:
     def inner(comp, seen):
         out = set()
         for line in comps.get(comp, []):
-            out.update(_STAGE.findall(line))
+            out.update(stage.findall(line))
             for callee in _CALLED.findall(line):
                 if callee not in seen:
                     seen.add(callee)
@@ -228,7 +232,7 @@ def _entry_op_stages(text: str) -> dict:
         if not m:
             continue
         name_ = re.search(r'op_name="([^"]*)"', line)
-        own = _STAGE.findall(name_.group(1)) if name_ else []
+        own = stage.findall(name_.group(1)) if name_ else []
         kind = re.search(r"kind=(k\w+)", line)
         ops[m.group(1)] = {
             "opcode": kind.group(1) if kind else m.group(2),
@@ -286,3 +290,70 @@ def test_unnamed_scatter_ops_name_mr1_inside(topo, n_dev):
     for name, op in ops.items():
         if op["opcode"] == "sort" and op["own"] is None:
             assert _downstream_stages(ops, name) == {"mr1"}, name
+
+
+#: the snowflake cell's fact-wide group on one chip: LINEITEM's rows
+#: without the set's comment word joined to PART's rows holding it and to
+#: ORDERS' rows without it, with CUSTOMER's segment rows one hop behind
+#: ORDERS (ORDERS stores its key and O_CUSTKEY)
+SF1_HOP_SIG_1CHIP = PlanSignature(
+    n_devices=1, vocab=216,
+    fact=RelationSig(rows=SF1_FACT_ROWS, cap=SF1_FACT_ROWS, text_len=16,
+                     key_width=3),
+    dims=(RelationSig(rows=8192, cap=8192, text_len=8, domain=262_144),
+          RelationSig(rows=2_097_152, cap=2_097_152, text_len=32,
+                      domain=2_097_152, key_width=2),
+          RelationSig(rows=32_768, cap=32_768, text_len=64, domain=262_144,
+                      parent=1, parent_key=1)),
+    accum=INT32_CHECKED)
+
+
+def test_fct_store_hop_program_fits_one_v5e(topo):
+    """The snowflake's per-CN program (the batcher pairs two requests, so
+    four CN slots) compiles at SF1 shapes with the kernel and fits HBM."""
+    mesh = Mesh(np.array(topo.devices[:1]), ("w",))
+    fn = _build_store_fn(SF1_HOP_SIG_1CHIP, mesh, "pallas", 4,
+                         reduce_cns=False)
+    lowered = jax.jit(fn).lower(*_store_args(SF1_HOP_SIG_1CHIP, mesh, 4))
+    assert "fct.hop" in lowered.as_text(debug_info=True)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("sig", [SF1_SIG_1CHIP, SF1_STAR_SIG_4CHIP],
+                         ids=["1chip", "2x2"])
+def test_star_store_program_holds_no_hop_op(topo, sig):
+    mesh = Mesh(np.array(topo.devices[:sig.n_devices]), ("w",))
+    fn = _build_store_fn(sig, mesh, "pallas", 4, reduce_cns=False,
+                         reduce_scatter=sig.n_devices > 1)
+    hlo = jax.jit(fn).lower(*_store_args(sig, mesh, 4)).as_text(
+        debug_info=True)          # op names ride the debug locations
+    assert "fct.mr1" in hlo
+    assert "fct.hop" not in hlo
+
+
+def test_unnamed_scatter_ops_of_a_hop_name_it_inside(topo):
+    """As for MR1's: the hop's scatter-adds compile to custom fusions with
+    no ``op_name`` of their own, and their fused computations name
+    ``fct.hop`` (every unnamed one still names ``fct.mr1``)."""
+    sig = PlanSignature(
+        n_devices=1, vocab=256,
+        fact=RelationSig(rows=2**16, cap=2**16, text_len=16, key_width=3),
+        dims=(RelationSig(rows=64, cap=64, text_len=8, domain=2**12),
+              RelationSig(rows=2**14, cap=2**14, text_len=32,
+                          domain=2**15, key_width=2),
+              RelationSig(rows=2**10, cap=2**10, text_len=64,
+                          domain=2**11, parent=1, parent_key=1)),
+        accum=INT32_CHECKED)
+    mesh = Mesh(np.array(topo.devices[:1]), ("w",))
+    fn = _build_store_fn(sig, mesh, "pallas", 4, reduce_cns=False)
+    text = jax.jit(fn).lower(*_store_args(sig, mesh, 4)).compile().as_text()
+    stages = _entry_op_stages(text)
+    hop = _entry_op_stages(text, _HOP)
+    custom = [n for n, op in stages.items()
+              if op["opcode"] == "kCustom" and op["own"] is None]
+    assert custom
+    for name in custom:
+        assert stages[name]["inner"] == {"mr1"}, (name, stages[name])
+    assert any(hop[name]["inner"] == {"hop"} for name in custom)
