@@ -37,8 +37,9 @@ C3 (transfer budget)
 C4 (bucketing)
     Every data-dependent input dim (rows, send capacity, text width, key
     domain) is a power of two no smaller than ``BUCKET_MIN``, and the
-    per-CN families' stack axis is a multiple of ``CN_BUCKET_MIN`` — the
-    shape lattice that makes the executable cache finite.
+    per-CN families' stack axis is one the engine's ``cn_stack`` rule
+    yields (1, 2 or a multiple of ``CN_BUCKET_MIN``) — the shape lattice
+    that makes the executable cache finite.
 
 ``check_all_contracts()`` runs every family under every *available* policy
 (int64-exact needs ``jax_enable_x64``; the x64 CI job covers it) on the
@@ -210,7 +211,7 @@ def check_contract(kind: str, sig: PlanSignature, n_stack: int, mesh,
                    histogram_backend: str = "ref") -> List[str]:
     """Check C1-C4 for one (family, signature) pair; returns failure strings
     prefixed ``kind[vocab=..,m=..,policy]``."""
-    from repro.runtime.engine import CN_BUCKET_MIN, vocab_padded
+    from repro.runtime.engine import cn_stack, vocab_padded
 
     tag = (f"{kind}[P={sig.n_devices},vocab={sig.vocab},m={sig.m},"
            f"{sig.accum.name}]")
@@ -232,11 +233,11 @@ def check_contract(kind: str, sig: PlanSignature, n_stack: int, mesh,
             failures.append(
                 f"{tag} C4: {label}.domain={rsig.domain} is not a power of "
                 f"two (signature escaped bucket_pow2)")
-    if not reduce_cns and n_stack % CN_BUCKET_MIN:
+    if not reduce_cns and cn_stack(n_stack) != n_stack:
         failures.append(
             f"{tag} C4: per-CN stack axis n_stack={n_stack} is not a "
-            f"multiple of CN_BUCKET_MIN={CN_BUCKET_MIN} — every window "
-            f"composition compiles a fresh program variant")
+            f"cn_stack size (1, 2 or a multiple of CN_BUCKET_MIN) — every "
+            f"window composition compiles a fresh program variant")
     if failures:
         return failures
 

@@ -54,7 +54,7 @@ _ENGINE_COUNTERS = ("hits", "misses", "traces", "evictions",
                     "column_bytes_shipped", "store_uploads", "store_hits",
                     "store_upload_bytes", "store_chunk_assembles",
                     "device_to_host_bytes", "groups_pruned", "pruned_rows",
-                    "routes_in_place", "hop_rows")
+                    "routes_in_place", "hop_rows", "pad_slots")
 
 
 def _cn_includes(cn: StarCN, role: str, dim_index: int) -> bool:

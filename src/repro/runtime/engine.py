@@ -67,9 +67,21 @@ from repro.runtime.batch import (BUCKET_MIN, PlanSignature, RelationSig,
 from repro.runtime.cache import ExecutableCache, default_cache
 
 
-CN_BUCKET_MIN = 4  # floor for bucketing the per-CN-output programs' N axis
+CN_BUCKET_MIN = 4  # bucket step of the per-CN-output programs' N axis
 TOPK_BUCKET_MIN = 16  # floor for bucketing the fct_topk family's k axis
 KW_BUCKET_MIN = 8  # floor for padding the keyword-exclusion id vector
+
+
+def cn_stack(n: int) -> int:
+    """CN slots the per-CN-output family stacks for a group of ``n`` CNs:
+    1 and 2 exactly, larger groups rounded up to a multiple of
+    CN_BUCKET_MIN.  That adds at most two program variants per signature
+    (1 and 2 slots) and runs at most CN_BUCKET_MIN - 1 null slots.  A stack
+    size ``s`` is on the lattice iff ``cn_stack(s) == s``."""
+    if n <= 2:
+        return max(n, 1)
+    return -(-n // CN_BUCKET_MIN) * CN_BUCKET_MIN
+
 
 #: structural filler for the fct_topk family's PlanSignature: the finalize
 #: program reads no relations (its input is the already-aggregated
@@ -391,6 +403,8 @@ class FCTEngine:
         # padded rows the fct.hop stage reads (hop_rows): a sub-dimension
         # and its parent, per CN slot of each dispatch
         self._c_hop_rows = self.metrics.counter("engine.hop_rows")
+        # null CN slots dispatched beyond each group's real CNs (pad_slots)
+        self._c_pad_slots = self.metrics.counter("engine.pad_slots")
         self._c_bytes = self.metrics.counter("engine.bytes_shipped")
         self._c_column_bytes = self.metrics.counter(
             "engine.column_bytes_shipped")
@@ -447,11 +461,12 @@ class FCTEngine:
         """Enqueue one stacked group on the device; returns the LAZY result
         (jax async dispatch) — callers block via ``_collect``.
 
-        The per-CN-output family additionally rounds the CN axis up to a
-        multiple of CN_BUCKET_MIN (zero-contribution null-plan padding): its
-        group sizes vary with the caller's batch composition, and without
-        rounding every size would compile a fresh program variant.  Padded
-        compute is capped at CN_BUCKET_MIN - 1 null CNs per group.  The
+        The per-CN-output family additionally rounds the CN axis up by
+        ``cn_stack`` (zero-contribution null-plan padding): its group sizes
+        vary with the caller's batch composition, and without rounding
+        every size would compile a fresh program variant.  Groups of 1 and
+        2 CNs run exactly that many slots; larger ones at most
+        CN_BUCKET_MIN - 1 null CNs (``engine.pad_slots`` counts them).  The
         summed family keeps exact N (deterministic per request, no padded
         compute on the latency-critical single-query path).
 
@@ -470,7 +485,7 @@ class FCTEngine:
         """
         n_stack = len(group)
         if not reduce_cns and self.bucket:
-            n_stack = -(-n_stack // CN_BUCKET_MIN) * CN_BUCKET_MIN
+            n_stack = cn_stack(n_stack)
         x64 = x64_flag()
         # vocab-sharded reduce-scatter only pays (and only differs from
         # psum) on real multi-device meshes; the aggregation kind rides the
@@ -508,12 +523,16 @@ class FCTEngine:
             self._c_bytes.inc(shipped)
             self._c_column_bytes.inc(columns)
         hop = hop_rows(sig, n_stack)
-        with obs_span("engine.enqueue", kind=kind, hop_rows=hop):
+        pad = n_stack - len(group)
+        with obs_span("engine.enqueue", kind=kind, hop_rows=hop,
+                      pad_slots=pad):
             out = fn(fact, dims)
         self._c_batches.inc()
         self._c_cns.inc(len(group))
         if hop:
             self._c_hop_rows.inc(hop)
+        if pad:
+            self._c_pad_slots.inc(pad)
         if sig.n_devices == 1:
             self._c_in_place.inc(n_stack * (1 + sig.m))
         return out
@@ -755,14 +774,15 @@ class FCTEngine:
     def stats(self) -> dict:
         out = self.cache.stats()
         (batches, cns, shipped, columns, d2h, g_pruned, rows_pruned,
-         in_place, hop) = self.metrics.values(
+         in_place, hop, pad) = self.metrics.values(
             self._c_batches, self._c_cns, self._c_bytes,
             self._c_column_bytes, self._c_d2h, self._c_groups_pruned,
-            self._c_pruned_rows, self._c_in_place, self._c_hop_rows)
+            self._c_pruned_rows, self._c_in_place, self._c_hop_rows,
+            self._c_pad_slots)
         out.update(batches_run=batches, cns_run=cns, bytes_shipped=shipped,
                    column_bytes_shipped=columns, device_to_host_bytes=d2h,
                    groups_pruned=g_pruned, pruned_rows=rows_pruned,
-                   routes_in_place=in_place, hop_rows=hop)
+                   routes_in_place=in_place, hop_rows=hop, pad_slots=pad)
         return out
 
 

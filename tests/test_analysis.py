@@ -361,6 +361,20 @@ def test_contract_c4_rejects_unbucketed_cn_stack():
     assert failures and "C4" in failures[0] and "n_stack=3" in failures[0]
 
 
+@pytest.mark.parametrize("kind", ["fct_batched_percn", "fct_store_percn"])
+@pytest.mark.parametrize("n_stack,ok", [(1, True), (2, True), (6, False)])
+def test_contract_c4_accepts_cn_stack_sizes_only(kind, n_stack, ok):
+    # the engine's cn_stack rule: 1 and 2 exact, larger groups a multiple
+    # of CN_BUCKET_MIN (6 is neither)
+    from repro.analysis.contracts import check_contract
+    failures = check_contract(kind, _one_sig(), n_stack, _mesh())
+    if ok:
+        assert failures == []
+    else:
+        assert failures and "C4" in failures[0]
+        assert f"n_stack={n_stack}" in failures[0]
+
+
 def test_contract_c2_catches_float_accumulator(monkeypatch):
     import jax.numpy as jnp
 

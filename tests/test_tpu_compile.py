@@ -308,13 +308,16 @@ SF1_HOP_SIG_1CHIP = PlanSignature(
     accum=INT32_CHECKED)
 
 
-def test_fct_store_hop_program_fits_one_v5e(topo):
-    """The snowflake's per-CN program (the batcher pairs two requests, so
-    four CN slots) compiles at SF1 shapes with the kernel and fits HBM."""
+@pytest.mark.parametrize("n_stack", [1, 4])
+def test_fct_store_hop_program_fits_one_v5e(topo, n_stack):
+    """The snowflake's per-CN program compiles at SF1 shapes with the
+    kernel and fits HBM: one slot (a group of one hop CN, as the cell's
+    groups are) and four (a full group)."""
     mesh = Mesh(np.array(topo.devices[:1]), ("w",))
-    fn = _build_store_fn(SF1_HOP_SIG_1CHIP, mesh, "pallas", 4,
+    fn = _build_store_fn(SF1_HOP_SIG_1CHIP, mesh, "pallas", n_stack,
                          reduce_cns=False)
-    lowered = jax.jit(fn).lower(*_store_args(SF1_HOP_SIG_1CHIP, mesh, 4))
+    lowered = jax.jit(fn).lower(*_store_args(SF1_HOP_SIG_1CHIP, mesh,
+                                             n_stack))
     assert "fct.hop" in lowered.as_text(debug_info=True)
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
